@@ -119,8 +119,9 @@ def run_staging(
     from repro.core.device_tier import (
         _DBUF_MIN_BYTES, build_snapshot_program, staged_snapshot_fetch,
     )
+    from repro.sharding.mesh import make_mesh
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     n = mbytes << 20
     sds = {
         "f32": jax.ShapeDtypeStruct((n // 8,), jnp.float32),

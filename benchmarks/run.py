@@ -95,6 +95,9 @@ def main() -> None:
         bench_roofline_table,
     )
 
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     smoke = "--smoke" in sys.argv[1:]
     trace_out = _trace_out_path(sys.argv[1:])
     if trace_out:

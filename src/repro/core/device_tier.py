@@ -66,7 +66,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import distribution as dist
 from repro.obs.trace import tracer
-from repro.sharding.mesh import shard_map
 from repro.utils.logging import get_logger
 
 log = get_logger("core.device_tier")
@@ -168,6 +167,17 @@ class SnapshotProgram:
     # One program per staging chunk (own copy, then one per bucket) — the
     # double-buffered D2H path driven by ``staged_snapshot_fetch``.
     snapshot_chunk_fns: tuple = ()
+
+
+def _take_rows(stacked: jax.Array, order: jax.Array) -> jax.Array:
+    """``stacked[order]`` for a short traced ``order``, as one dynamic slice
+    per row. The TPU compiler takes time linear in the operand's size to
+    compile a gather, which for multi-GiB exchange buffers means minutes;
+    a dynamic slice compiles in constant time."""
+    return jnp.stack([
+        jax.lax.dynamic_index_in_dim(stacked, order[i], 0, keepdims=False)
+        for i in range(order.shape[0])
+    ])
 
 
 def _to_u32_local(x: jax.Array) -> jax.Array:
@@ -437,7 +447,7 @@ def build_snapshot_program(
                 stacked = jnp.stack(slots)                      # (g, words)
                 # canonical member order + zero rows past a ragged group's size
                 order = (jnp.arange(g) - pos) % jnp.maximum(k_local, 1)
-                canonical = jnp.take(stacked, order, axis=0)
+                canonical = _take_rows(stacked, order)
                 canonical = jnp.where(
                     (jnp.arange(g) < k_local)[:, None], canonical, jnp.uint32(0)
                 )
@@ -552,13 +562,13 @@ def build_snapshot_program(
             payload["own"] = treedef.unflatten([jnp.copy(x) for x in leaves])
         if buckets:
             in_specs, out_specs = _fused_specs(buckets, validate)
-            # Pallas calls carry no replication rule in older jax releases, so
-            # the striped (on-device-encode) program opts out of the check;
-            # its outputs are fully varying anyway.
-            fn = shard_map(
+            # Pallas calls carry no varying-axes rule, so the striped
+            # (on-device-encode) program opts out of the check; its outputs
+            # are fully varying anyway.
+            fn = jax.shard_map(
                 _make_fused_local(buckets, validate),
                 mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=not striped,
+                check_vma=not striped,
             )
             payload.update(fn(*_fused_args(leaves, buckets)))
         elif validate:
@@ -575,10 +585,10 @@ def build_snapshot_program(
     def _make_chunk_fn(bucket):
         in_specs, out_specs = _fused_specs([bucket], False)
         fused = jax.jit(  # built + jitted once: chunk calls hit the jit cache
-            shard_map(
+            jax.shard_map(
                 _make_fused_local([bucket], False),
                 mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=not striped,
+                check_vma=not striped,
             )
         )
 
@@ -611,7 +621,7 @@ def build_snapshot_program(
                 # Re-replicate over axes the leaf doesn't vary on (the fused
                 # buffer varies on the bucket union): numerically the copies
                 # are identical; all_gather[0] makes it explicit. The rep
-                # checker cannot prove this — hence check_rep=False below.
+                # checker cannot prove this — hence check_vma=False below.
                 leaf_axes: set[str] = set()
                 for e in _full_rank(leaves_ps[i], len(leaves_sds[i].shape)):
                     leaf_axes.update(_axes_of(e))
@@ -638,9 +648,9 @@ def build_snapshot_program(
             for b in buckets
             for i in b.leaf_idx
         )
-        fn = shard_map(
+        fn = jax.shard_map(
             _restore_local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         outs = fn(*[partner[b.tag] for b in buckets])
         result = {}
@@ -1072,7 +1082,7 @@ def build_striped_restore_program(
                     slots.append(cur)
                 stacked = jnp.stack(slots)                  # (g, S·sw)
                 order = (jnp.arange(g) - pos) % k_mine
-                canon = jnp.take(stacked, order, axis=0)    # row c = member c
+                canon = _take_rows(stacked, order)          # row c = member c
                 # 2. splice the full blob: stripe s lives at member s mod
                 #    k_mine, slot s // k_mine (divisible worlds: member s,
                 #    slot 0 — the legacy layout).
@@ -1121,7 +1131,7 @@ def build_striped_restore_program(
                 slots.append(cur)
             stacked = jnp.stack(slots)
             order = (jnp.arange(g) - pos) % k_mine
-            canonical = jnp.take(stacked, order, axis=0)   # (g, words)
+            canonical = _take_rows(stacked, order)         # (g, words)
             canonical = jnp.where(
                 (jnp.arange(g) < k_mine)[:, None], canonical, jnp.uint32(0)
             )
@@ -1168,9 +1178,9 @@ def build_striped_restore_program(
         P(*_full_rank(leaves_ps[i], len(leaves_sds[i].shape)))
         for b in buckets for i in b.leaf_idx
     )
-    _restore_prog = jax.jit(shard_map(
+    _restore_prog = jax.jit(jax.shard_map(
         _restore_local, mesh=mesh, in_specs=_in_specs, out_specs=_out_specs,
-        check_rep=False,
+        check_vma=False,
     ))
 
     def restore_fn(state, parity, decode_rows, survivor_mask):

@@ -49,6 +49,10 @@ from collections import OrderedDict
 
 import numpy as np
 
+from repro.utils.logging import get_logger
+
+log = get_logger("core.gf256")
+
 GF_POLY = 0x11D  # x^8 + x^4 + x^3 + x^2 + 1, the standard RS(255) polynomial
 _ORDER = 255
 
@@ -329,7 +333,9 @@ class _SwarBackend:
 
 
 class _JaxBackend:
-    """Jitted jax-CPU Horner bit-plane product on uint8 lanes — the same
+    """Jitted Horner bit-plane product on uint8 lanes, pinned to the host's
+    CPU device whatever the default backend is (on a TPU host the default
+    device is the chip, and host encode chunks must not cross PCIe) — the same
     xtime recurrence as the Pallas kernels (kernels/rs_encode.py
     ``_xtime_u32``), restated per byte lane so arbitrary lengths and
     alignments need no packing. XLA fuses the whole chain into a single
@@ -338,7 +344,8 @@ class _JaxBackend:
 
     Compiled programs are cached per (coefficient rows, k, padded length);
     lengths are bucketed to powers of two so the cache stays small, and an
-    LRU bound + lock keep it safe under the async worker pool."""
+    LRU bound + lock keep it safe under the async worker pool. Where JAX
+    has no CPU backend the first call raises, and the probe drops it."""
 
     name = "jax"
     _MAX_FNS = 64
@@ -347,6 +354,14 @@ class _JaxBackend:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._fns: OrderedDict[tuple, object] = OrderedDict()
+        self._cpu = None
+
+    def _device(self):
+        if self._cpu is None:
+            import jax
+
+            self._cpu = jax.local_devices(backend="cpu")[0]
+        return self._cpu
 
     def _compiled(self, rows: tuple[tuple[int, ...], ...], k: int, nb: int):
         key = (rows, k, nb)
@@ -400,7 +415,10 @@ class _JaxBackend:
             stack[i, : seg.nbytes] = seg
             stack[i, seg.nbytes :] = 0  # zero padding is a GF no-op
         fn = self._compiled(rows, k, nb)
-        res = np.asarray(fn(stack))
+        import jax
+
+        # Committed to the CPU device, so the jitted product runs there.
+        res = np.asarray(fn(jax.device_put(stack, self._device())))
         for t, dst in enumerate(dsts):
             dend = min(end, dst.nbytes)
             if lo >= dend:
@@ -467,7 +485,8 @@ def _probe_backends() -> str:
                 t0 = time.perf_counter()
                 backend.matrix_into(dsts, srcs, rows, 0, L)
                 dt = min(dt, time.perf_counter() - t0)
-        except Exception:  # pragma: no cover - a broken backend loses the probe
+        except Exception as e:  # a broken backend loses the probe, loudly
+            log.warning("GF backend %r dropped from the probe: %r", name, e)
             continue
         gbps = k * L / max(dt, 1e-9) / 1e9
         _PROBE_GBPS[name] = gbps

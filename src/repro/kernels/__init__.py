@@ -5,7 +5,7 @@
   * quantize   — fused int8 snapshot/gradient compression
 
 Each kernel ships with a pure-jnp oracle in ``ref.py`` and a jit'd public
-wrapper in ``ops.py``; on CPU the kernels execute in interpret mode.
+wrapper in ``ops.py``; off a TPU backend the kernels execute in interpret mode.
 """
 
 from repro.kernels import ops, ref

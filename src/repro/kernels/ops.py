@@ -1,14 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles padding/reshaping to kernel-native tiles, dtype views, and the
-Pallas-vs-reference dispatch: on TPU the compiled kernels run natively; on CPU
-(this container) they run in interpret mode so the kernel *bodies* are what is
-validated. ``REPRO_KERNELS=ref`` forces the jnp oracles (used by A/B tests).
+Handles padding/reshaping to kernel-native tiles and dtype views.
+``_interpret`` is the one place that decides how a kernel runs: compiled on
+a TPU backend, in Pallas interpret mode anywhere else (so CPU tests validate
+the kernel *bodies*). The jnp oracles in ``ref.py`` are test references only.
 """
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -17,14 +16,9 @@ import numpy as np
 
 from repro.kernels import checksum as _checksum_k
 from repro.kernels import quantize as _quantize_k
-from repro.kernels import ref
 from repro.kernels import reshard as _reshard_k
 from repro.kernels import rs_encode as _rs_k
 from repro.kernels import xor_parity as _xor_k
-
-
-def _use_ref() -> bool:
-    return os.environ.get("REPRO_KERNELS", "pallas") == "ref"
 
 
 def _interpret() -> bool:
@@ -57,21 +51,17 @@ def _pad_to(x: jax.Array, multiple: int) -> jax.Array:
 # XOR parity
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("interpret",))
-def xor_reduce(stacked: jax.Array, interpret: bool | None = None) -> jax.Array:
+@jax.jit
+def xor_reduce(stacked: jax.Array) -> jax.Array:
     """XOR over axis 0 of (k, n) uint32. Returns (n,) uint32."""
     assert stacked.ndim == 2 and stacked.dtype == jnp.uint32
-    if _use_ref():
-        return ref.xor_reduce(stacked)
     k, n = stacked.shape
     tile = _xor_k.SUBLANES * _xor_k.BLOCK_COLS
     npad = (-n) % tile
     padded = jnp.pad(stacked, ((0, 0), (0, npad))) if npad else stacked
     rows = padded.shape[1] // _xor_k.BLOCK_COLS
     x3 = padded.reshape(k, rows, _xor_k.BLOCK_COLS)
-    out = _xor_k.xor_reduce_pallas(
-        x3, interpret=_interpret() if interpret is None else interpret
-    )
+    out = _xor_k.xor_reduce_pallas(x3, interpret=_interpret())
     return out.reshape(-1)[:n]
 
 
@@ -87,33 +77,22 @@ def xor_encode_arrays(arrays: list[jax.Array]) -> jax.Array:
 # Reed-Solomon GF(2^8) parity (multi-failure redundancy codec)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("coefs", "interpret"))
-def gf256_matmul(
-    stacked: jax.Array,
-    coefs: tuple[tuple[int, ...], ...],
-    interpret: bool | None = None,
-) -> jax.Array:
+@partial(jax.jit, static_argnames=("coefs",))
+def gf256_matmul(stacked: jax.Array, coefs: tuple[tuple[int, ...], ...]) -> jax.Array:
     """RS parity over axis 0 of (k, n) uint32 (4 packed GF bytes per word).
 
     coefs is the static (m, k) generator (tuple of tuples, hashable for jit).
-    Returns (m, n) uint32. The ref oracle works byte-wise, so the dispatch
-    bitcasts around it; the Pallas kernel consumes the packed words directly.
+    Returns (m, n) uint32; the Pallas kernel consumes the packed words directly.
     """
     assert stacked.ndim == 2 and stacked.dtype == jnp.uint32
     k, n = stacked.shape
     assert len(coefs[0]) == k, (len(coefs[0]), k)
-    if _use_ref():
-        u8 = jax.lax.bitcast_convert_type(stacked.reshape(k, n, 1), jnp.uint8)
-        out = ref.gf256_matmul(u8.reshape(k, n * 4), coefs)
-        return jax.lax.bitcast_convert_type(out.reshape(len(coefs), n, 4), jnp.uint32)
     tile = _rs_k.SUBLANES * _rs_k.BLOCK_COLS
     npad = (-n) % tile
     padded = jnp.pad(stacked, ((0, 0), (0, npad))) if npad else stacked
     rows = padded.shape[1] // _rs_k.BLOCK_COLS
     x3 = padded.reshape(k, rows, _rs_k.BLOCK_COLS)
-    out = _rs_k.rs_encode_pallas(
-        x3, coefs, interpret=_interpret() if interpret is None else interpret
-    )
+    out = _rs_k.rs_encode_pallas(x3, coefs, interpret=_interpret())
     return out.reshape(len(coefs), -1)[:, :n]
 
 
@@ -130,18 +109,13 @@ def gf256_matmul_dyn(stacked: jax.Array, coefs: jax.Array) -> jax.Array:
     """Erasure DECODE over axis 0 of (k, n) uint32 with a runtime (m, k)
     coefficient matrix (gf256.erasure_decode_matrix rows — which ranks died
     is data, not a compile-time constant, so the decode program compiles once
-    and serves every failure combination). Returns (m, n) uint32; the ref
-    oracle works byte-wise, so the dispatch bitcasts around it."""
+    and serves every failure combination). Returns (m, n) uint32."""
     from repro.kernels import rs_decode as _rsd_k
 
     assert stacked.ndim == 2 and stacked.dtype == jnp.uint32
     k, n = stacked.shape
     assert coefs.ndim == 2 and coefs.shape[1] == k, (coefs.shape, k)
     m = coefs.shape[0]
-    if _use_ref():
-        u8 = jax.lax.bitcast_convert_type(stacked.reshape(k, n, 1), jnp.uint8)
-        out = ref.gf256_matmul_dyn(u8.reshape(k, n * 4), coefs)
-        return jax.lax.bitcast_convert_type(out.reshape(m, n, 4), jnp.uint32)
     tile = _rsd_k.SUBLANES * _rsd_k.BLOCK_COLS
     npad = (-n) % tile
     padded = jnp.pad(stacked, ((0, 0), (0, npad))) if npad else stacked
@@ -164,8 +138,8 @@ def rs_decode_arrays(arrays: list[jax.Array], coefs: jax.Array) -> jax.Array:
 # Reshard row gather (elastic N-to-M recovery)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("interpret",))
-def gather_rows(src: jax.Array, idx: jax.Array, interpret: bool | None = None) -> jax.Array:
+@jax.jit
+def gather_rows(src: jax.Array, idx: jax.Array) -> jax.Array:
     """out[i] = src[idx[i]] for src (rows, cols), idx (rows_out,) int32.
 
     The device-tier move of the elastic reshard executor: the repartition
@@ -173,14 +147,10 @@ def gather_rows(src: jax.Array, idx: jax.Array, interpret: bool | None = None) -
     shard. Columns are lane-padded here; callers keep the original width.
     """
     assert src.ndim == 2 and idx.ndim == 1
-    if _use_ref():
-        return ref.gather_rows(src, idx)
     cols = src.shape[1]
     pad = (-cols) % _reshard_k.LANE_COLS
     padded = jnp.pad(src, ((0, 0), (0, pad))) if pad else src
-    out = _reshard_k.gather_rows_pallas(
-        padded, idx, interpret=_interpret() if interpret is None else interpret
-    )
+    out = _reshard_k.gather_rows_pallas(padded, idx, interpret=_interpret())
     return out[:, :cols]
 
 
@@ -188,20 +158,16 @@ def gather_rows(src: jax.Array, idx: jax.Array, interpret: bool | None = None) -
 # Checksum
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("interpret",))
-def checksum(x: jax.Array, interpret: bool | None = None) -> jax.Array:
+@jax.jit
+def checksum(x: jax.Array) -> jax.Array:
     """Fletcher-style dual checksum of any array -> (2,) uint32."""
     u = as_u32(x)
-    if _use_ref():
-        return ref.checksum(u)
     tile = _checksum_k.SUBLANES * _checksum_k.LANE_COLS
-    u = _pad_to(u, tile)  # zero padding leaves both sums unchanged... s2 shifts!
-    # NOTE: zero pad contributes 0 to both sums (0 * idx == 0), so padding is
+    # Zero padding contributes 0 to both sums (0 * idx == 0), so padding is
     # checksum-transparent even for the weighted sum.
+    u = _pad_to(u, tile)
     x2 = u.reshape(-1, _checksum_k.LANE_COLS)
-    return _checksum_k.checksum_pallas(
-        x2, interpret=_interpret() if interpret is None else interpret
-    )
+    return _checksum_k.checksum_pallas(x2, interpret=_interpret())
 
 
 def tree_checksum(tree) -> jax.Array:
@@ -221,10 +187,8 @@ def tree_checksum(tree) -> jax.Array:
 # Quantization
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("block", "interpret"))
-def quantize_blockwise(
-    x: jax.Array, block: int = 256, interpret: bool | None = None
-) -> tuple[jax.Array, jax.Array]:
+@partial(jax.jit, static_argnames=("block",))
+def quantize_blockwise(x: jax.Array, block: int = 256) -> tuple[jax.Array, jax.Array]:
     """x: (n,) float -> (q (n_pad,) int8, scales (n_pad/block,) f32).
 
     n is padded up to a ROWS_PER_TILE*block multiple; dequantize_blockwise
@@ -234,21 +198,15 @@ def quantize_blockwise(
     assert block == _quantize_k.QBLOCK, "kernel is specialized to QBLOCK"
     xpad = _pad_to(x, block * _quantize_k.ROWS_PER_TILE)
     xb = xpad.reshape(-1, block)
-    if _use_ref():
-        return ref.quantize_blockwise(xpad, block)
-    q, s = _quantize_k.quantize_pallas(
-        xb, interpret=_interpret() if interpret is None else interpret
-    )
-    return q.reshape(-1), s
+    q, s = _quantize_k.quantize_pallas(xb, interpret=_interpret())
+    return q.reshape(-1), s.reshape(-1)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def dequantize_blockwise(q: jax.Array, scale: jax.Array, interpret: bool | None = None) -> jax.Array:
+@jax.jit
+def dequantize_blockwise(q: jax.Array, scale: jax.Array) -> jax.Array:
     block = q.shape[0] // scale.shape[0]
-    if _use_ref():
-        return ref.dequantize_blockwise(q, scale)
     assert block == _quantize_k.QBLOCK
     out = _quantize_k.dequantize_pallas(
-        q.reshape(-1, block), scale, interpret=_interpret() if interpret is None else interpret
+        q.reshape(-1, block), scale.reshape(-1, 1), interpret=_interpret()
     )
     return out.reshape(-1)

@@ -62,7 +62,7 @@ def _rs_decode_kernel(c_ref, x_ref, o_ref, *, m: int, k: int):
 
 
 def rs_decode_pallas(
-    stacked: jax.Array, coefs: jax.Array, interpret: bool = True
+    stacked: jax.Array, coefs: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """stacked: (k, rows, cols) uint32, rows % 8 == 0, cols % BLOCK_COLS == 0.
 

@@ -68,7 +68,7 @@ def _rs_kernel(x_ref, o_ref, *, coefs: tuple[tuple[int, ...], ...]):
 
 
 def rs_encode_pallas(
-    stacked: jax.Array, coefs: tuple[tuple[int, ...], ...], interpret: bool = True
+    stacked: jax.Array, coefs: tuple[tuple[int, ...], ...], *, interpret: bool
 ) -> jax.Array:
     """stacked: (k, rows, cols) uint32, rows % 8 == 0, cols % BLOCK_COLS == 0.
 

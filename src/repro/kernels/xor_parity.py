@@ -30,7 +30,7 @@ def _xor_kernel(x_ref, o_ref, *, k: int):
     o_ref[...] = acc
 
 
-def xor_reduce_pallas(stacked: jax.Array, interpret: bool = True) -> jax.Array:
+def xor_reduce_pallas(stacked: jax.Array, *, interpret: bool) -> jax.Array:
     """stacked: (k, rows, cols) uint32 with rows % 8 == 0, cols % BLOCK_COLS == 0.
 
     Returns (rows, cols) uint32 = XOR over axis 0. Wrapper-level padding and

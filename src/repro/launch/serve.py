@@ -2,6 +2,16 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b --reduced \
         --batch 4 --prompt-len 8 --gen 32 --kill-at 10:2
+
+``--reduced`` swaps in a tiny same-family config for CPU runs. Without it
+the architecture serves at its published size on the default JAX device: on
+one TPU v5e, for example,
+
+    PYTHONPATH=src python -m repro.launch.serve --arch mamba2-780m \
+        --batch 4 --prompt-len 512 --gen 16 --ckpt-every 8 --kill-at 10:2
+
+``chip_smoke.py`` at the repository root drives this entry point on the chip
+and checks the regenerated tokens against a run without the kill.
 """
 
 from __future__ import annotations
@@ -14,15 +24,17 @@ import numpy as np
 from repro.configs import get_config, list_archs
 from repro.core.checkpoint import EngineConfig
 from repro.models import build_model
+from repro.models.model import Model
 from repro.obs.trace import tracer
 from repro.runtime.failures import FailureInjector
 from repro.runtime.server import Server, ServerConfig
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.serve")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true")
@@ -59,17 +71,12 @@ def main() -> None:
     ap.add_argument("--metrics-port", type=int, default=None,
                     help="serve the engine's Prometheus registry on "
                          "http://127.0.0.1:PORT/metrics (0 = free port)")
-    args = ap.parse_args()
+    return ap
 
-    if args.trace_out:
-        tracer().enable()
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    if cfg.is_encoder:
-        raise SystemExit(f"{cfg.name} is encoder-only (no decode step)")
-    model = build_model(cfg)
+def build_server(args: argparse.Namespace, model: Model) -> Server:
+    """The server this entry point runs for ``args``, with the ``--kill-at`` /
+    ``--silent-kill-at`` schedules wired into its failure injector."""
 
     def _parse_kills(spec: str | None) -> dict[int, list[int]]:
         schedule: dict[int, list[int]] = {}
@@ -100,12 +107,33 @@ def main() -> None:
             codec=args.codec, parity_group=args.parity_group, rs_parity=args.rs_parity
         ),
     )
-    server = Server(model, scfg, injector=injector)
+    return Server(model, scfg, injector=injector)
+
+
+def make_prompts(args: argparse.Namespace, vocab_size: int) -> np.ndarray:
+    """The seeded random prompts this entry point serves."""
+    return np.random.default_rng(0).integers(
+        0, vocab_size, (args.batch, args.prompt_len), dtype=np.int32
+    )
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+
+    use_compile_cache()
+    if args.trace_out:
+        tracer().enable()
+
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    if cfg.is_encoder:
+        raise SystemExit(f"{cfg.name} is encoder-only (no decode step)")
+    model = build_model(cfg)
+    server = build_server(args, model)
     if args.metrics_port is not None:
         server.start_metrics_server(args.metrics_port)
-    prompts = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (args.batch, args.prompt_len), dtype=np.int32
-    )
+    prompts = make_prompts(args, cfg.vocab_size)
     extra = {}
     if cfg.vision_tokens:
         extra["vision"] = jax.random.normal(

@@ -3,8 +3,17 @@
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --reduced \
         --steps 200 --batch 8 --seq 128 --mtbf 3600 --spares 2
 
-On this CPU container use ``--reduced`` (full configs are exercised by the
-dry-run); on a real pod drop it and pass --mesh to shard over devices.
+``--reduced`` swaps in a tiny same-family config, which is what a CPU run
+can afford. Without it the architecture runs at its published size on the
+default JAX device: on one TPU v5e, for example,
+
+    PYTHONPATH=src python -m repro.launch.train --arch mamba2-780m \
+        --batch 4 --seq 2048 --steps 6 --period 2 --checkpoint-mode async \
+        --codec xor --parity-group 4
+
+The train step runs unsharded on that one device; the ``--hosts`` virtual
+failure-domain ranks split its checkpoint in host memory. ``chip_smoke.py``
+at the repository root drives this entry point on the chip end to end.
 """
 
 from __future__ import annotations
@@ -12,20 +21,20 @@ from __future__ import annotations
 import argparse
 import json
 
-import jax
-
 from repro.configs import get_config, list_archs
 from repro.core.checkpoint import EngineConfig
 from repro.models import build_model
+from repro.models.model import Model
 from repro.obs.trace import tracer
 from repro.runtime.failures import FailureInjector
 from repro.runtime.trainer import Trainer, TrainerConfig
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.train")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true", help="tiny same-family config (CPU)")
@@ -97,22 +106,15 @@ def main() -> None:
     ap.add_argument("--metrics-port", type=int, default=None,
                     help="serve the engine's Prometheus registry on "
                          "http://127.0.0.1:PORT/metrics (0 = pick a free port)")
-    args = ap.parse_args()
-    if args.cold_restart and not args.tier_dir:
-        ap.error("--cold-restart requires --tier-dir")
+    return ap
 
-    if args.trace_out:
-        tracer().enable()
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    model = build_model(cfg)
-    log.info("arch %s: %s params (%s active)", cfg.name, f"{model.n_params:,}",
-             f"{model.n_active_params:,}")
-
-    injector = None
-    if args.inject_mtbf:
+def build_trainer(
+    args: argparse.Namespace, model: Model, injector: FailureInjector | None = None
+) -> Trainer:
+    """The trainer this entry point runs for ``args``; ``injector`` replaces the
+    ``--inject-mtbf`` one (a caller's scripted kill schedule)."""
+    if injector is None and args.inject_mtbf:
         injector = FailureInjector(
             args.hosts, mtbf_rank_s=args.inject_mtbf, step_time_s=1.0, seed=17
         )
@@ -144,7 +146,27 @@ def main() -> None:
             restore_mode=args.restore_mode,
         ),
     )
-    trainer = Trainer(model, tcfg, injector=injector)
+    return Trainer(model, tcfg, injector=injector)
+
+
+def main() -> None:
+    ap = build_parser()
+    args = ap.parse_args()
+    if args.cold_restart and not args.tier_dir:
+        ap.error("--cold-restart requires --tier-dir")
+
+    use_compile_cache()
+    if args.trace_out:
+        tracer().enable()
+
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    model = build_model(cfg)
+    log.info("arch %s: %s params (%s active)", cfg.name, f"{model.n_params:,}",
+             f"{model.n_active_params:,}")
+
+    trainer = build_trainer(args, model)
     metrics_server = None
     if args.metrics_port is not None:
         from repro.runtime.server import start_metrics_server

@@ -121,7 +121,10 @@ def apply(
     # ---- intra-chunk (quadratic, masked decay matrix) --------------------
     seg = dAcs[:, :, :, None, :] - dAcs[:, :, None, :, :]  # (B,nc,Q,Q,H) = a_i - a_j
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    Ldecay = jnp.where(mask[None, None, :, :, None], jnp.exp(seg), 0.0)
+    # Mask before the exp: above the diagonal seg = a_i - a_j > 0 grows with
+    # the chunk length and overflows f32, and where(mask, inf, 0) has a NaN
+    # gradient even though its value is 0.
+    Ldecay = jnp.exp(jnp.where(mask[None, None, :, :, None], seg, -jnp.inf))
     att = jnp.einsum("bcln,bcsn->bcls", Cc, Bc)  # (B,nc,Q,Q)
     xdt = xc.astype(jnp.float32) * dtc[..., None]  # (B,nc,Q,H,P)
     y_diag = jnp.einsum("bcls,bclsh,bcshp->bclhp", att, Ldecay, xdt)

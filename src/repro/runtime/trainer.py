@@ -49,6 +49,33 @@ log = get_logger("runtime.trainer")
 _TR = tracer()
 
 
+def state_pspecs(model: Model, mesh, moment_dtype: Any = jnp.float32) -> dict[str, Any]:
+    """PartitionSpecs of the train state on ``mesh``: params by the model's
+    rules, optimizer state ZeRO-1 over the data axis."""
+    rules = model.rules
+    p_specs = model.abstract_params
+    opt_specs = abstract_opt_state(p_specs, moment_dtype)
+    return {
+        "params": tree_pspecs(p_specs, rules, mesh),
+        "opt": {
+            "master": tree_zero1_pspecs(opt_specs["master"], rules, mesh),
+            "m": tree_zero1_pspecs(opt_specs["m"], rules, mesh),
+            "v": tree_zero1_pspecs(opt_specs["v"], rules, mesh),
+        },
+        "step": jax.sharding.PartitionSpec(),
+    }
+
+
+def state_shape_dtypes(model: Model, moment_dtype: Any = jnp.float32) -> dict[str, Any]:
+    """ShapeDtypeStructs of the train state (params, AdamW state, step)."""
+    o = abstract_opt_state(model.abstract_params, moment_dtype)
+    return {
+        "params": specs_to_shape_dtype(model.abstract_params),
+        "opt": specs_to_shape_dtype(o),
+        "step": jax.ShapeDtypeStruct((), jnp.int32),
+    }
+
+
 @dataclass
 class TrainerConfig:
     batch: int = 8
@@ -122,8 +149,8 @@ class Trainer:
 
         # -- sharding plan against the PRODUCTION mesh (abstract) -------------
         prod_mesh = abstract_mesh(("data", 16), ("model", 16))
-        pspecs = self._state_pspecs(prod_mesh)
-        sds = self._state_sds()
+        pspecs = state_pspecs(model, prod_mesh, tcfg.moment_dtype)
+        sds = state_shape_dtypes(model, tcfg.moment_dtype)
         self.plan = ShardPlan.from_pspecs(sds, pspecs)
 
         # -- cluster + engine + scheduler -------------------------------------
@@ -202,30 +229,11 @@ class Trainer:
         for tier in self.engine.persistent_tiers:
             tier.every = self.mlsched.flush_every(1)
 
-    def _state_pspecs(self, mesh) -> dict[str, Any]:
-        rules = self.model.rules
-        p_specs = self.model.abstract_params
-        opt_specs = abstract_opt_state(p_specs, self.tcfg.moment_dtype)
-        return {
-            "params": tree_pspecs(p_specs, rules, mesh),
-            "opt": {
-                "master": tree_zero1_pspecs(opt_specs["master"], rules, mesh),
-                "m": tree_zero1_pspecs(opt_specs["m"], rules, mesh),
-                "v": tree_zero1_pspecs(opt_specs["v"], rules, mesh),
-            },
-            "step": jax.sharding.PartitionSpec(),
-        }
-
-    def _state_sds(self) -> dict[str, Any]:
-        p = specs_to_shape_dtype(self.model.abstract_params)
-        o = abstract_opt_state(self.model.abstract_params, self.tcfg.moment_dtype)
-        return {
-            "params": p,
-            "opt": specs_to_shape_dtype(o),
-            "step": jax.ShapeDtypeStruct((), jnp.int32),
-        }
-
     def _set_state(self, np_state: dict[str, Any]) -> None:
+        # Release the live device state before uploading the restored one:
+        # holding both would need twice the state in device memory, which a
+        # chip filled by its optimizer state does not have.
+        self.state = None
         self.state = jax.tree.map(jnp.asarray, np_state)
 
     def _build_train_step(self):

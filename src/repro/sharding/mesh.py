@@ -2,57 +2,31 @@
 
 from __future__ import annotations
 
+import jax
 import numpy as np
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None) -> Mesh:
+    """The one concrete-mesh constructor: every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which the models'
+    ``with_sharding_constraint`` calls and the device tier's padded slicing of
+    uneven leaves are refused; every mesh in the program and its tests comes
+    from here. ``devices`` defaults to ``jax.devices()``; pass described
+    topology devices to compile for a chip that is not attached.
+    """
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def abstract_mesh(*axes: tuple[str, int]) -> AbstractMesh:
-    """Version-portable ``AbstractMesh`` constructor from (name, size) pairs.
-
-    jax >= 0.4.36 takes a single shape-tuple of (name, size) pairs; earlier
-    releases took (sizes, names). Call as ``abstract_mesh(("data", 16),
-    ("model", 16))``.
-    """
-    try:
-        return AbstractMesh(tuple(axes))
-    except TypeError:
-        sizes = tuple(s for _, s in axes)
-        names = tuple(n for n, _ in axes)
-        return AbstractMesh(sizes, names)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """Version-portable ``shard_map``: top-level ``jax.shard_map`` when the
-    release exports it, ``jax.experimental.shard_map`` otherwise (the
-    experimental module is only imported on releases that need it).
-
-    ``check_rep=False`` disables the replication/VMA check (needed e.g. for
-    the device-tier restore program, which re-replicates leaves out of a
-    fused buffer via all_gather — numerically replicated but not statically
-    provable). The flag is spelled ``check_rep`` on older releases and
-    ``check_vma`` on newer ones; both are attempted."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore[no-redef]
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_rep:
-        return fn(f, **kwargs)
-    for flag in ("check_rep", "check_vma"):
-        try:
-            return fn(f, **kwargs, **{flag: False})
-        except TypeError:
-            continue  # this release spells the kwarg differently
-    # Never degrade silently: callers pass check_rep=False because their
-    # program cannot pass the check (Pallas bodies, all_gather
-    # re-replication) — a clear error here beats an opaque trace-time one.
-    raise TypeError(
-        "this jax release's shard_map accepts neither check_rep nor "
-        "check_vma; cannot disable the replication check"
-    )
+    """``AbstractMesh`` with Auto axes from (name, size) pairs, e.g.
+    ``abstract_mesh(("data", 16), ("model", 16))``."""
+    names = tuple(n for n, _ in axes)
+    sizes = tuple(s for _, s in axes)
+    return AbstractMesh(sizes, names, axis_types=(AxisType.Auto,) * len(names))
 
 
 def mesh_axis_size(mesh: Mesh, axes: tuple[str, ...] | str | None) -> int:

@@ -23,9 +23,10 @@ def test_exchange_roll_semantics_and_restore():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"w": jax.ShapeDtypeStruct((8, 6), jnp.float32),
                "rep": jax.ShapeDtypeStruct((5,), jnp.float32)}
         ps = {"w": P("data", "model"), "rep": P()}
@@ -64,10 +65,11 @@ def test_fused_single_program_many_leaves():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
         from repro.utils.hlo import analyze_hlo_collectives
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         L = 6
         sds = {f"w{i}": jax.ShapeDtypeStruct((8, 4 + 2 * i), jnp.float32) for i in range(L)}
         ps = {f"w{i}": (P("data", "model") if i % 2 else P("data", None)) for i in range(L)}
@@ -98,9 +100,10 @@ def test_uneven_leaf_padded_exchange():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"u": jax.ShapeDtypeStruct((7, 2), jnp.float32)}
         ps = {"u": P("data", None)}
         prog = build_snapshot_program(mesh, sds, ps, validate=False)
@@ -119,10 +122,11 @@ def test_compressed_exchange_shrinks_traffic():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
         from repro.utils.hlo import analyze_hlo_collectives
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"w": jax.ShapeDtypeStruct((1024, 512), jnp.float32)}
         ps = {"w": P("data", "model")}
         full = build_snapshot_program(mesh, sds, ps, validate=False, include_own_copy=False)
@@ -142,12 +146,13 @@ def test_compressed_exchange_shrinks_traffic():
 _PARITY_ORACLE = textwrap.dedent(
     """
     import jax, jax.numpy as jnp, numpy as np
+    from repro.sharding.mesh import make_mesh
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.core.device_tier import build_snapshot_program
     from repro.core.codec import XorCodec, RSCodec
     from repro.core import distribution as dist
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     sds = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
            "v": jax.ShapeDtypeStruct((8,), jnp.bfloat16),
            "b": jax.ShapeDtypeStruct((16,), jnp.int8)}
@@ -203,9 +208,10 @@ def test_unaligned_local_shard_words():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"a": jax.ShapeDtypeStruct((4, 2), jnp.int8),
                "b": jax.ShapeDtypeStruct((8, 3), jnp.int8)}
         ps = {"a": P("data", None), "b": P("data", None)}
@@ -246,11 +252,12 @@ def test_device_stripes_and_pcie_accounting():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
         from repro.core.codec import XorCodec
         from repro.core import distribution as dist
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32)}
         ps = {"w": P("data", "model")}
         rng = np.random.default_rng(1)
@@ -288,12 +295,13 @@ _STRIPED_RESTORE = textwrap.dedent(
     """
     import itertools
     import jax, jax.numpy as jnp, numpy as np
+    from repro.sharding.mesh import make_mesh
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.core.device_tier import (
         build_snapshot_program, build_striped_restore_program, striped_decode_rows,
     )
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     sds = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
            "v": jax.ShapeDtypeStruct((8,), jnp.bfloat16),
            "b": jax.ShapeDtypeStruct((16,), jnp.int8)}
@@ -393,9 +401,10 @@ def test_staged_snapshot_fetch_double_buffered_bit_identical():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program, staged_snapshot_fetch
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
                "v": jax.ShapeDtypeStruct((8,), jnp.bfloat16)}
         ps = {"w": P("data", "model"), "v": P("data")}
@@ -429,9 +438,10 @@ def test_ragged_world_takes_stripe_path_not_fallback():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32)}
         ps = {"w": P("data", "model")}
         w = jnp.asarray(np.random.default_rng(1).standard_normal((8, 4)), jnp.float32)
@@ -466,10 +476,11 @@ def test_stripe_pcie_accounting_exact_divisible_ragged_and_full_blob():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_snapshot_program
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
                "b": jax.ShapeDtypeStruct((8,), jnp.float32)}
         ps = {"w": P("data", "model"), "b": P("data")}
@@ -506,10 +517,11 @@ def test_mirror_program_routes_primary_buckets_to_shadow_twins():
     code = textwrap.dedent(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.device_tier import build_mirror_program
         from repro.utils.hlo import analyze_hlo_collectives
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sds = {"w": jax.ShapeDtypeStruct((8, 6), jnp.float32),
                "rep": jax.ShapeDtypeStruct((5,), jnp.float32)}
         ps = {"w": P("data", "model"), "rep": P()}

@@ -211,3 +211,25 @@ def test_vocab_padding_masked():
     logits, _ = model.prefill(params, tokens=toks)
     assert logits.shape[-1] == 256
     assert np.all(np.asarray(logits[..., 200:]) < -1e29)
+
+
+def test_ssd_gradient_finite_at_full_chunk():
+    """Mamba2: above the diagonal of a chunk the decay exponent a_i - a_j is
+    positive and grows with the chunk length; at the default 128-token chunk
+    it overflows f32. The masked entries must contribute a zero gradient,
+    not inf * 0 = NaN (which the first AdamW step spreads to every leaf)."""
+    from repro.models import ssm
+    from repro.sharding.spec import init_tree
+
+    cfg = CONFIGS["mamba2-780m"].reduced()
+    params = init_tree(KEY, ssm.abstract_params(cfg))
+    params["dt_bias"] = jnp.full_like(params["dt_bias"], 2.0)  # dt ~ 2 per token
+    x = jax.random.normal(KEY, (1, ssm.CHUNK, cfg.d_model))
+
+    def loss(p):
+        out, _ = ssm.apply(p, x, cfg)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    grads = jax.grad(loss)(params)
+    for name, g in grads.items():
+        assert np.isfinite(np.asarray(g)).all(), name

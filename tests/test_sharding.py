@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.mesh import abstract_mesh
+from repro.sharding.mesh import abstract_mesh, make_mesh
 from repro.sharding.axes import (
     FSDP_RULES,
     TP_RULES,
@@ -99,7 +99,8 @@ def test_hlo_parser_on_real_module():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.sharding.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         def f(x, w):
             y = x @ w
             return jax.lax.with_sharding_constraint(
@@ -162,7 +163,7 @@ def test_small_mesh_compile_reduced(shape_kind):
     from repro.launch.steps import build_step
 
     cfg = CONFIGS["llama3.2-1b"].reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     small = dataclasses.replace(SHAPES[shape_kind], seq_len=64, global_batch=2)
     # SHAPES is one shared dict across modules; patching it here patches the
     # view build_step reads.
