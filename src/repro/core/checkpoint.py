@@ -528,10 +528,6 @@ class CheckpointEngine:
             "ckpt_stage_seconds", "Create-pipeline stage seconds per unit.",
             labelnames=("phase",),
         )
-        self._h_rate = self.registry.histogram(
-            "ckpt_stage_bytes_per_second",
-            "Create-pipeline stage throughput per unit.", labelnames=("phase",),
-        )
         self._h_restore = self.registry.histogram(
             "restore_stage_seconds", "Restore-pipeline stage seconds per chunk.",
             labelnames=("phase",),
@@ -731,7 +727,7 @@ class CheckpointEngine:
             with _TR.span("capture", eng=self._obs_id, gen=gen):
                 self._fault_hook("before_create")
                 packed_partner, manifests, exch_sums, chunk_sums = self._capture(
-                    alive0, meta
+                    alive0, meta, gen
                 )
                 self._fault_hook("after_create")
         except FaultDuringCheckpoint as e:
@@ -744,11 +740,6 @@ class CheckpointEngine:
 
         self.stats.last_capture_s = time.perf_counter() - t0
         self._h_stage.observe(self.stats.last_capture_s, phase="capture")
-        if self.stats.last_capture_s > 0:
-            self._h_rate.observe(
-                self.stats.last_bytes_staged / self.stats.last_capture_s,
-                phase="capture",
-            )
         pending = _PendingCheckpoint(
             packed_partner, manifests, alive0, t0, exch_sums=exch_sums,
             chunk_sums=chunk_sums, gen=gen,
@@ -761,7 +752,7 @@ class CheckpointEngine:
         return True
 
     def _capture(
-        self, alive0: set[int], meta: dict[str, Any] | None
+        self, alive0: set[int], meta: dict[str, Any] | None, gen: int
     ) -> tuple[
         dict[str, list[tuple[Any, Manifest]]], dict[tuple[int, str], Any], dict, dict
     ]:
@@ -773,6 +764,9 @@ class CheckpointEngine:
         packed_partner: dict[str, list[tuple[Any, Manifest]]] = {}
         coords_tables: dict[str, Any] = {}
         bytes_staged = 0
+        bytes_replicated = 0  # leaves every rank holds whole, per rank copy
+        eng = self._obs_id
+
         def _lease_for(r: int, key: tuple):
             """HostStore.lease bound for pack_bytes's callback form (sizing
             happens inside pack_bytes's single traversal); None for ranks
@@ -782,35 +776,47 @@ class CheckpointEngine:
                 return None
             return lambda nbytes: store.lease(key, nbytes)
 
-        for name, ent in self._entities.items():
-            shards = ent.snapshot_shards(self.n_ranks)
-            rows: list[tuple[Any, Manifest]] = []
-            for r, shard in enumerate(shards):
-                rows.append(pack_bytes(shard, lease=_lease_for(r, ("own", name))))
-                bytes_staged += rows[-1][0].nbytes
-            packed[name] = rows
-            if hasattr(ent, "shard_coords"):
-                # Global-coordinate manifest: each shard records its slice
-                # of the logical entity, the layer elastic N-to-M restore
-                # repartitions on. The full table is tiny and replicated
-                # with every store's meta (like the parity manifests).
-                table = ent.shard_coords(self.n_ranks)
-                for r, (_, man) in enumerate(packed[name]):
-                    man.coords = table[r]
-                coords_tables[name] = table
-            if hasattr(ent, "partner_payload"):
-                # Exchange only the uniquely-owned subset (replicated
-                # leaves exist on every rank already — paper §5.2.1).
-                sub_rows: list[tuple[Any, Manifest]] = []
+        # Every entity's shards first (a sharded state's D2H fetch is timed
+        # inside its snapshot_shards), then all packing under one span.
+        snaps = {
+            name: ent.snapshot_shards(self.n_ranks)
+            for name, ent in self._entities.items()
+        }
+        with _TR.span("capture_pack", eng=eng, gen=gen) as sp:
+            for name, ent in self._entities.items():
+                shards = snaps.pop(name)
+                rows: list[tuple[Any, Manifest]] = []
                 for r, shard in enumerate(shards):
-                    subset = ent.partner_payload(shard, self.n_ranks)
-                    sub_rows.append(
-                        pack_bytes(subset, lease=_lease_for(r, ("exch", name)))
+                    rows.append(pack_bytes(shard, lease=_lease_for(r, ("own", name))))
+                    bytes_staged += rows[-1][0].nbytes
+                packed[name] = rows
+                if hasattr(ent, "replicated_nbytes"):
+                    bytes_replicated += sum(
+                        ent.replicated_nbytes(shard, self.n_ranks) for shard in shards
                     )
-                    bytes_staged += sub_rows[-1][0].nbytes
-                packed_partner[name] = sub_rows
-            else:
-                packed_partner[name] = packed[name]
+                if hasattr(ent, "shard_coords"):
+                    # Global-coordinate manifest: each shard records its slice
+                    # of the logical entity, the layer elastic N-to-M restore
+                    # repartitions on. The full table is tiny and replicated
+                    # with every store's meta (like the parity manifests).
+                    table = ent.shard_coords(self.n_ranks)
+                    for r, (_, man) in enumerate(packed[name]):
+                        man.coords = table[r]
+                    coords_tables[name] = table
+                if hasattr(ent, "partner_payload"):
+                    # Exchange only the uniquely-owned subset (replicated
+                    # leaves exist on every rank already — paper §5.2.1).
+                    sub_rows: list[tuple[Any, Manifest]] = []
+                    for r, shard in enumerate(shards):
+                        subset = ent.partner_payload(shard, self.n_ranks)
+                        sub_rows.append(
+                            pack_bytes(subset, lease=_lease_for(r, ("exch", name)))
+                        )
+                        bytes_staged += sub_rows[-1][0].nbytes
+                    packed_partner[name] = sub_rows
+                else:
+                    packed_partner[name] = packed[name]
+            sp.label(bytes=bytes_staged, replicated_bytes=bytes_replicated)
 
         # Manifests are tiny: replicate all of them with every store's meta so
         # any survivor can unpack any origin's rebuilt bytes. (Compression in
@@ -844,27 +850,28 @@ class CheckpointEngine:
         codec_specs = {
             name: self._codec_spec(self._codec_for(name)) for name in packed
         }
-        for r in alive0:
-            payload = StorePayload(meta=dict(meta or {}))
-            if coords_tables:
-                payload.meta["coords"] = dict(coords_tables)
-            payload.meta["manifests"] = manifests
-            payload.meta["codecs"] = codec_specs
-            for name, rows in packed.items():
-                flat, man = rows[r]
-                payload.own[name] = (flat, man)
-                if (
-                    self._codec_for(name).striped
-                    and packed_partner[name] is not packed[name]
-                ):
-                    payload.own_exch[name] = packed_partner[name][r]
+        with _TR.span("capture_checksum", eng=eng, gen=gen):
+            for r in alive0:
+                payload = StorePayload(meta=dict(meta or {}))
+                if coords_tables:
+                    payload.meta["coords"] = dict(coords_tables)
+                payload.meta["manifests"] = manifests
+                payload.meta["codecs"] = codec_specs
+                for name, rows in packed.items():
+                    flat, man = rows[r]
+                    payload.own[name] = (flat, man)
+                    if (
+                        self._codec_for(name).striped
+                        and packed_partner[name] is not packed[name]
+                    ):
+                        payload.own_exch[name] = packed_partner[name][r]
+                    if self.cfg.validate:
+                        payload.meta.setdefault("checksums", {})[name] = np_checksum(flat)
                 if self.cfg.validate:
-                    payload.meta.setdefault("checksums", {})[name] = np_checksum(flat)
-            if self.cfg.validate:
-                payload.meta["exch_checksums"] = exch_sums
-            if self.cfg.delta:
-                payload.meta["exch_chunk_sums"] = chunk_sums
-            self.stores[r].buffer.write(payload)
+                    payload.meta["exch_checksums"] = exch_sums
+                if self.cfg.delta:
+                    payload.meta["exch_chunk_sums"] = chunk_sums
+                self.stores[r].buffer.write(payload)
         self.stats.last_bytes_staged = bytes_staged
         return packed_partner, manifests, exch_sums, chunk_sums
 
@@ -967,10 +974,7 @@ class CheckpointEngine:
                 with _TR.span("transfer", eng=eng, gen=gen, group=u[0], entity=u[3]):
                     t = time.perf_counter()
                     nb = self._transfer_unit(u, encoded.pop(i - 1), pending)
-                    dt = time.perf_counter() - t
-                    self._h_stage.observe(dt, phase="transfer")
-                    if dt > 0:
-                        self._h_rate.observe(nb / dt, phase="transfer")
+                    self._h_stage.observe(time.perf_counter() - t, phase="transfer")
                     total += nb
             if 0 <= i - 2 < n:
                 u = units[i - 2]
@@ -1690,7 +1694,8 @@ class CheckpointEngine:
         with _TR.span(
             "restore", eng=self._obs_id, failed=len(failed), mode=self.cfg.restore_mode
         ):
-            recovered = self._recover_all(alive, failed)
+            with _TR.span("restore_rebuild", eng=self._obs_id):
+                recovered = self._recover_all(alive, failed)
             for name, ent in self._entities.items():
                 ent.restore_shards(recovered[name])
 
@@ -2469,7 +2474,7 @@ class CheckpointEngine:
         with _TR.span(
             "restore", eng=self._obs_id, failed=len(failed),
             mode=self.cfg.restore_mode, elastic=new_n_ranks,
-        ):
+        ), _TR.span("restore_rebuild", eng=self._obs_id):
             recovered = self._recover_all(alive, failed)  # pipelined or sync
         for name, ent in self._entities.items():
             shards = recovered[name]

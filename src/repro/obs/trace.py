@@ -1,21 +1,29 @@
 """Span tracer — nested, labeled, thread-aware timelines (DESIGN.md §13).
 
-The create pipeline (CAPTURE / ENCODE / TRANSFER / VERIFY / COMMIT / tier
-FLUSH) and the restore pipeline (TRANSFER / DECODE / DEQ / VERIFY /
-escalation) emit spans through the process-global :func:`tracer`, including
-from background drain workers and the flush thread — so one exported trace
-shows a whole generation's overlap structure across every thread lane.
+The create pipeline (CAPTURE and its D2H / PACK / CHECKSUM children, ENCODE /
+TRANSFER / VERIFY / COMMIT, tier FLUSH), the restore pipeline (REBUILD with
+its TRANSFER / DECODE / DEQ / VERIFY / escalation, then MERGE and UPLOAD) and
+the serving tick emit spans through the process-global :func:`tracer`,
+including from background drain workers and the flush thread — so one
+exported trace shows a whole generation's overlap structure across every
+thread lane.
 
-Design constraints (the ISSUE 6 overhead budget):
+Design constraints:
 
   * **Disabled is free.** ``tracer().span(...)`` first checks ``enabled``;
     when off it returns the shared ``_NOOP`` singleton without touching the
     event buffer, formatting a string, or taking a lock. The only cost at a
     disabled call site is the attribute check plus building the (small)
     kwargs dict.
-  * **Enabled is cheap.** A span is two ``perf_counter`` reads and one
-    locked list append at close; no string formatting ever happens on the
-    hot path (labels are stored raw and serialized only at export).
+  * **Enabled is cheap.** A span is two ``perf_counter`` reads, one
+    ``jax.profiler.TraceAnnotation`` of the span's name and one locked list
+    append at close; no string formatting ever happens on the hot path
+    (labels are stored raw and serialized only at export).
+  * **On the profiler's clock.** While enabled, every span is mirrored into
+    the JAX profiler's trace as an annotation of the same name (no labels),
+    on the host line of the thread that ran it: a ``jax.profiler`` trace
+    shows the engine's phases beside the device ops. Without a running
+    profiler the annotation records nothing.
   * **Spans always balance.** Spans are context managers, so an exception
     anywhere inside (mid-pipeline kill, abort, escalation) still closes the
     span; per-thread open-depth is tracked so tests can assert balance.
@@ -44,12 +52,15 @@ class _NoopSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def label(self, **args: Any) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0", "tid")
+    __slots__ = ("tracer", "name", "args", "t0", "tid", "parent", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict[str, Any]) -> None:
         self.tracer = tracer
@@ -57,16 +68,30 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        tr = self.tracer
         self.tid = threading.get_ident()
-        self.tracer._enter(self.tid)
+        tr._enter(self.tid)
+        self.parent = getattr(tr._local, "top", None)
+        tr._local.top = self
+        self.annotation = tr._annotation(self.name) if tr._annotation else None
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
-        self.tracer._record(self.name, self.t0, t1, self.tid, self.args)
-        self.tracer._exit(self.tid)
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
+        tr = self.tracer
+        tr._local.top = self.parent
+        tr._record(self.name, self.t0, t1, self.tid, self.args)
+        tr._exit(self.tid)
         return False
+
+    def label(self, **args: Any) -> None:
+        """Add labels known only once the span's work is done (byte counts)."""
+        self.args.update(args)
 
 
 class Tracer:
@@ -78,10 +103,18 @@ class Tracer:
         self._events: list[tuple[str, float, float, int, dict]] = []
         self._instants: list[tuple[str, float, int, dict]] = []
         self._depth: dict[int, int] = {}
+        self._local = threading.local()  # .top: this thread's innermost open span
+        self._annotation: Any = None     # jax.profiler.TraceAnnotation, once enabled
         self._t0 = time.perf_counter()
 
     # -- control ----------------------------------------------------------
     def enable(self) -> None:
+        if self._annotation is None:
+            try:
+                from jax.profiler import TraceAnnotation
+            except ImportError:  # no jax: spans stay on perf_counter alone
+                TraceAnnotation = None
+            self._annotation = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:
@@ -101,6 +134,15 @@ class Tracer:
         if not self.enabled:
             return _NOOP
         return _Span(self, name, args)
+
+    def child(self, name: str, **args: Any):
+        """Like :meth:`span`, carrying the labels of this thread's innermost
+        open span as well (``eng``/``gen`` of the capture that called an
+        entity's snapshot, say)."""
+        if not self.enabled:
+            return _NOOP
+        top = getattr(self._local, "top", None)
+        return _Span(self, name, {**top.args, **args} if top is not None else args)
 
     def instant(self, name: str, **args: Any) -> None:
         """A zero-duration marker event (failures, commits, kills)."""

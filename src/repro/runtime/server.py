@@ -38,6 +38,7 @@ from repro.sharding.spec import specs_to_shape_dtype
 from repro.utils.logging import get_logger
 
 log = get_logger("runtime.server")
+_TR = tracer()
 
 
 class MetricsServer:
@@ -249,55 +250,66 @@ class Server:
         ticks = 0
         while produced < n_tokens:
             try:
-                self.cluster.barrier("decode")
-                # Commit an overlapped checkpoint from the previous decode
-                # boundary (its pipeline ran behind the last steps).
-                pending = self.engine.finalize_async()
-                if pending is False:
-                    raise ProcessFaultException(
-                        sorted(self.cluster.failed), "checkpoint"
-                    )
-                if pending and self.replica is not None:
-                    self._replica_tick()
-                # staged tier flush starts here, behind the next decode steps
-                self.engine.kick_tier_flush()
-                for r in self.injector.kills_at_step(ticks):
-                    self.cluster.kill(r)
-                for r in self.injector.silent_kills_at_step(ticks):
-                    self.cluster.kill(r, cause="silent_death", silent=True)
-                if self.replica is not None:
-                    for r in self.injector.replica_kills_at_step(ticks):
-                        self.replica.cluster.kill(r, cause="replica_host_failure")
-                ticks += 1
-                self._hb_tick += 1
-                if self.heartbeat is not None:
-                    lost = self.heartbeat.observe(
-                        self.cluster.alive(), self._hb_tick
-                    )
-                    if lost:
-                        for r in lost:
-                            self.injector.note_detection(r)
-                        raise ProcessFaultException(lost, "heartbeat")
-                self.cluster.barrier("decode")
-
-                pos = int(self.sessions["pos"])
-                tok = self.sessions["tokens"][:, pos]
-                logits, cache = self._decode(self.params, self.sessions["cache"], tok, jnp.asarray(pos, jnp.int32))
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                tokens = self.sessions["tokens"].at[:, pos + 1].set(nxt)
-                self.sessions = {"cache": cache, "tokens": tokens, "pos": jnp.asarray(pos + 1, jnp.int32)}
-                produced = self._produced()
-
-                if produced % self.scfg.checkpoint_every_tokens == 0:
-                    if self.scfg.checkpoint_mode == "async":
-                        # Capture now; the pipeline overlaps the next decodes.
-                        ok = self.engine.checkpoint_async({"pos": pos + 1})
-                    else:
-                        ok = self.engine.checkpoint({"pos": pos + 1})
-                        if ok and self.replica is not None:
+                with _TR.span("decode_tick"):
+                    with _TR.span("tick_control"):
+                        self.cluster.barrier("decode")
+                        # Commit an overlapped checkpoint from the previous decode
+                        # boundary (its pipeline ran behind the last steps).
+                        pending = self.engine.finalize_async()
+                        if pending is False:
+                            raise ProcessFaultException(
+                                sorted(self.cluster.failed), "checkpoint"
+                            )
+                        if pending and self.replica is not None:
                             self._replica_tick()
-                    if not ok:
-                        raise ProcessFaultException(sorted(self.cluster.failed), "checkpoint")
+                        # staged tier flush starts here, behind the next decode steps
+                        self.engine.kick_tier_flush()
+                        for r in self.injector.kills_at_step(ticks):
+                            self.cluster.kill(r)
+                        for r in self.injector.silent_kills_at_step(ticks):
+                            self.cluster.kill(r, cause="silent_death", silent=True)
+                        if self.replica is not None:
+                            for r in self.injector.replica_kills_at_step(ticks):
+                                self.replica.cluster.kill(r, cause="replica_host_failure")
+                        ticks += 1
+                        self._hb_tick += 1
+                        if self.heartbeat is not None:
+                            lost = self.heartbeat.observe(
+                                self.cluster.alive(), self._hb_tick
+                            )
+                            if lost:
+                                for r in lost:
+                                    self.injector.note_detection(r)
+                                raise ProcessFaultException(lost, "heartbeat")
+                        self.cluster.barrier("decode")
+                        pos = int(self.sessions["pos"])
+                        tok = self.sessions["tokens"][:, pos]
+
+                    with _TR.span("decode_step"):
+                        logits, cache = self._decode(
+                            self.params, self.sessions["cache"], tok, jnp.asarray(pos, jnp.int32)
+                        )
+                    with _TR.span("tick_update"):
+                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        tokens = self.sessions["tokens"].at[:, pos + 1].set(nxt)
+                        self.sessions = {
+                            "cache": cache, "tokens": tokens,
+                            "pos": jnp.asarray(pos + 1, jnp.int32),
+                        }
+                        produced = self._produced()
+
+                    if produced % self.scfg.checkpoint_every_tokens == 0:
+                        if self.scfg.checkpoint_mode == "async":
+                            # Capture now; the pipeline overlaps the next decodes.
+                            ok = self.engine.checkpoint_async({"pos": pos + 1})
+                        else:
+                            ok = self.engine.checkpoint({"pos": pos + 1})
+                            if ok and self.replica is not None:
+                                self._replica_tick()
+                        if not ok:
+                            raise ProcessFaultException(
+                                sorted(self.cluster.failed), "checkpoint"
+                            )
             except ProcessFaultException as e:
                 log.warning("serving fault: %s", e)
                 self.recover()
@@ -338,15 +350,16 @@ class Server:
         """Recovery entry: the replication rung sits ABOVE the codec ladder —
         a synced shadow team is promoted (no blocking rebuild) and only teams
         without a promotable shadow fall into the restore machinery."""
-        if self.replica is not None and self.replica.can_promote:
-            self._promote_replica()
-        else:
-            self._recover_current()
-        if self.heartbeat is not None:
-            # Rebuild against the (possibly promoted/resized) engine so the
-            # liveness gauge lands in the live registry, and re-arm beats.
-            self.heartbeat = self._new_heartbeat()
-            self.heartbeat.reset(self.cluster.alive(), self._hb_tick)
+        with _TR.span("recover"):
+            if self.replica is not None and self.replica.can_promote:
+                self._promote_replica()
+            else:
+                self._recover_current()
+            if self.heartbeat is not None:
+                # Rebuild against the (possibly promoted/resized) engine so the
+                # liveness gauge lands in the live registry, and re-arm beats.
+                self.heartbeat = self._new_heartbeat()
+                self.heartbeat.reset(self.cluster.alive(), self._hb_tick)
 
     def _promote_replica(self) -> None:
         """Zero-downtime failover: swap the shadow team in as the serving
@@ -362,11 +375,11 @@ class Server:
         self.cluster, self.engine = self.replica.release()
         failed_shadow = sorted(self.cluster.failed)
         gen = self.replica.synced_gen
-        tracer().instant(
+        _TR.instant(
             "replica_promote", gen=gen,
             failed_primary=len(failed_primary), failed_shadow=len(failed_shadow),
         )
-        with tracer().span("replica_promote_restore", gen=gen):
+        with _TR.span("replica_promote_restore", gen=gen):
             self._recover_current()
         stall = time.perf_counter() - t0
         self.promotions += 1

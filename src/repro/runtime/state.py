@@ -22,6 +22,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.serialization import LeafSlice
+from repro.obs.trace import tracer
+
+_TR = tracer()
 
 DATA_AXES = ("pod", "data")
 
@@ -107,7 +110,9 @@ class ShardedStateEntity:
 
     # -- snapshot ------------------------------------------------------------
     def snapshot_shards(self, n_ranks: int) -> list[Any]:
-        state = jax.device_get(self._get())
+        live = self._get()
+        with _TR.child("capture_d2h", bytes=sum(x.nbytes for x in jax.tree.leaves(live))):
+            state = jax.device_get(live)
         leaves = self.plan.treedef.flatten_up_to(state)
         shard_leaves: list[list[np.ndarray]] = [[] for _ in range(n_ranks)]
         for i, leaf in enumerate(leaves):
@@ -132,6 +137,16 @@ class ShardedStateEntity:
             if self.plan.split_dim(i, n_ranks) is not None
         }
 
+    def replicated_nbytes(self, shard: Any, n_ranks: int) -> int:
+        """Bytes of ``shard``'s leaves that every rank holds whole (the
+        complement of :meth:`partner_payload`)."""
+        leaves = self.plan.treedef.flatten_up_to(shard)
+        return sum(
+            leaves[i].nbytes
+            for i in range(len(leaves))
+            if self.plan.split_dim(i, n_ranks) is None
+        )
+
     def merge_payload(self, partner_subset: Any, survivor_full: Any, n_ranks: int) -> Any:
         """Rebuild a dead rank's payload: uniquely-owned leaves from the
         partner copy + replicated leaves from any survivor's own snapshot."""
@@ -146,14 +161,19 @@ class ShardedStateEntity:
         assert set(shards) == set(range(n)), f"missing origins: {sorted(shards)}"
         per_origin = [self.plan.treedef.flatten_up_to(shards[r]) for r in range(n)]
         out = []
-        for i in range(len(self.plan.dims)):
-            pieces = [np.asarray(per_origin[r][i]) for r in range(n)]
-            dim = self.plan.split_dim(i, n)
-            if dim is None:
-                out.append(pieces[0])
-            else:
-                out.append(np.concatenate(pieces, axis=dim))
-        self._set(self.plan.treedef.unflatten(out))
+        with _TR.child("restore_merge"):
+            for i in range(len(self.plan.dims)):
+                pieces = [np.asarray(per_origin[r][i]) for r in range(n)]
+                dim = self.plan.split_dim(i, n)
+                if dim is None:
+                    out.append(pieces[0])
+                else:
+                    out.append(np.concatenate(pieces, axis=dim))
+        with _TR.child("restore_upload"):
+            self._set(self.plan.treedef.unflatten(out))
+            if _TR.enabled:
+                # Time the transfer itself, not its enqueue.
+                jax.block_until_ready(self._get())
 
 
 class RngEntity:

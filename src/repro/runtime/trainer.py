@@ -372,39 +372,40 @@ class Trainer:
         (inside ``engine.restore``) rehydrates the newest valid disk
         generation and recovery re-runs against it. Failures within
         tolerance never touch disk."""
-        if not self.engine.has_valid_checkpoint:
-            if self.engine.has_tier_data():
-                # Full-restart policy: every in-memory snapshot died with its
-                # host; all ranks rejoin and the engine escalates internally.
-                log.warning("no in-memory checkpoint; escalating to the tier ladder")
-                self.cluster.restart_all()
-                meta = self.engine.restore()
-                self.n_recoveries += 1
-                log.info("recovered from the tier ladder to step %s", meta.get("step"))
-                return
-            raise RuntimeError(
-                "fault before the first checkpoint and no persistent tier configured"
+        with _TR.span("recover"):
+            if not self.engine.has_valid_checkpoint:
+                if self.engine.has_tier_data():
+                    # Full-restart policy: every in-memory snapshot died with its
+                    # host; all ranks rejoin and the engine escalates internally.
+                    log.warning("no in-memory checkpoint; escalating to the tier ladder")
+                    self.cluster.restart_all()
+                    meta = self.engine.restore()
+                    self.n_recoveries += 1
+                    log.info("recovered from the tier ladder to step %s", meta.get("step"))
+                    return
+                raise RuntimeError(
+                    "fault before the first checkpoint and no persistent tier configured"
+                )
+            report = self.cluster.stabilize(self.tcfg.recovery_policy)  # revoke+shrink
+            if report.policy == "elastic":
+                meta = self._elastic_recover(report.n_ranks_after)
+            elif report.policy == "shrink":
+                meta = self._shrink_engine(report)
+            else:
+                meta = self.engine.restore()  # Algorithm 4 under the hood
+            # Restored entities include the data pipeline + timers + train state;
+            # the loop continues from the checkpointed step.
+            self.n_recoveries += 1
+            s = self.engine.stats
+            log.info(
+                "recovered to step %s (policy=%s, codec=%s/t%d, load_factor=%.2f, "
+                "restore=%s %.3fs: %d chunks, %.1f MiB rebuilt)",
+                meta.get("step"), report.policy,
+                self.engine.codec.name, self.engine.codec.tolerance(),
+                report.load_factor,
+                self.tcfg.engine.restore_mode, s.last_restore_s,
+                s.last_restore_chunks, s.last_restore_bytes_rebuilt / 2**20,
             )
-        report = self.cluster.stabilize(self.tcfg.recovery_policy)  # revoke+shrink
-        if report.policy == "elastic":
-            meta = self._elastic_recover(report.n_ranks_after)
-        elif report.policy == "shrink":
-            meta = self._shrink_engine(report)
-        else:
-            meta = self.engine.restore()  # Algorithm 4 under the hood
-        # Restored entities include the data pipeline + timers + train state;
-        # the loop continues from the checkpointed step.
-        self.n_recoveries += 1
-        s = self.engine.stats
-        log.info(
-            "recovered to step %s (policy=%s, codec=%s/t%d, load_factor=%.2f, "
-            "restore=%s %.3fs: %d chunks, %.1f MiB rebuilt)",
-            meta.get("step"), report.policy,
-            self.engine.codec.name, self.engine.codec.tolerance(),
-            report.load_factor,
-            self.tcfg.engine.restore_mode, s.last_restore_s,
-            s.last_restore_chunks, s.last_restore_bytes_rebuilt / 2**20,
-        )
 
     def _shrink_engine(self, report) -> dict[str, Any]:
         """Elastic shrink: restore from the OLD world's surviving stores, then
