@@ -222,8 +222,8 @@ class Server:
             journal=self.engine.journal,
         )
 
-    def _set_sessions(self, np_sessions: dict[str, Any]) -> None:
-        self.sessions = jax.tree.map(jnp.asarray, np_sessions)
+    def _set_sessions(self, sessions: dict[str, Any]) -> None:
+        self.sessions = sessions
 
     # ------------------------------------------------------------------ #
     def prefill(self, prompts: np.ndarray, **extra_inputs: Any) -> None:

@@ -18,6 +18,7 @@ from dataclasses import dataclass
 from typing import Any, Callable
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -93,6 +94,11 @@ class ShardedStateEntity:
     Exposes ``shard_coords`` (the plan's global-coordinate manifest), which
     the engine attaches to each shard's serialization Manifest — the layer
     the elastic N-to-M restore path repartitions on.
+
+    ``release``, when given, drops the live device state; a restore calls it
+    before its first host-to-device transfer, so the device never holds the
+    old state and the restored one at once. ``set_state`` receives a tree of
+    device arrays.
     """
 
     def __init__(
@@ -100,9 +106,11 @@ class ShardedStateEntity:
         get_state: Callable[[], Any],
         set_state: Callable[[Any], None],
         plan: ShardPlan,
+        release: Callable[[], None] | None = None,
     ) -> None:
         self._get = get_state
         self._set = set_state
+        self._release = release
         self.plan = plan
 
     def shard_coords(self, n_ranks: int) -> list[list[LeafSlice]]:
@@ -157,23 +165,42 @@ class ShardedStateEntity:
 
     # -- restore ---------------------------------------------------------
     def restore_shards(self, shards: dict[int, Any]) -> None:
+        """Upload every origin's pieces in one batched transfer and join the
+        split leaves on the device: the host copies nothing, and the device
+        holds at most the state plus its largest leaf. Returns once the
+        whole restored state is on the device."""
         n = max(shards) + 1
         assert set(shards) == set(range(n)), f"missing origins: {sorted(shards)}"
         per_origin = [self.plan.treedef.flatten_up_to(shards[r]) for r in range(n)]
-        out = []
-        with _TR.child("restore_merge"):
-            for i in range(len(self.plan.dims)):
-                pieces = [np.asarray(per_origin[r][i]) for r in range(n)]
-                dim = self.plan.split_dim(i, n)
-                if dim is None:
-                    out.append(pieces[0])
-                else:
-                    out.append(np.concatenate(pieces, axis=dim))
+        # A split leaf travels as its n pieces, a replicated one (or any leaf
+        # of a one-rank world) once.
+        dims = [self.plan.split_dim(i, n) if n > 1 else None
+                for i in range(len(self.plan.dims))]
+        host = [
+            [per_origin[r][i] for r in range(n)] if dim is not None else [per_origin[0][i]]
+            for i, dim in enumerate(dims)
+        ]
+        if self._release is not None:
+            self._release()
         with _TR.child("restore_upload"):
-            self._set(self.plan.treedef.unflatten(out))
-            if _TR.enabled:
-                # Time the transfer itself, not its enqueue.
-                jax.block_until_ready(self._get())
+            pieces = jax.block_until_ready(jax.device_put(host))
+        out = []
+        with _TR.child("restore_merge") as span:
+            merged = 0
+            for i, dim in enumerate(dims):
+                if dim is None:
+                    out.append(pieces[i][0])
+                    continue
+                # Wait for each join before freeing its pieces: joins enqueued
+                # ahead of the device each hold their output while every
+                # piece is still held, up to twice the state on the device.
+                leaf = jnp.concatenate(pieces[i], axis=dim).block_until_ready()
+                for p in pieces[i]:
+                    p.delete()
+                merged += leaf.nbytes
+                out.append(leaf)
+            span.label(bytes=merged)
+        self._set(self.plan.treedef.unflatten(out))
 
 
 class RngEntity:

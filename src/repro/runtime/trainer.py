@@ -161,7 +161,8 @@ class Trainer:
         self.timers.attach_metrics(self.engine.registry)
         self.engine.register(
             "train_state",
-            ShardedStateEntity(lambda: self.state, self._set_state, self.plan),
+            ShardedStateEntity(lambda: self.state, self._set_state, self.plan,
+                               release=self._release_state),
         )
         self.engine.register("data_pipeline", self.data)
         self.engine.register("timers", self.timers)
@@ -229,12 +230,17 @@ class Trainer:
         for tier in self.engine.persistent_tiers:
             tier.every = self.mlsched.flush_every(1)
 
-    def _set_state(self, np_state: dict[str, Any]) -> None:
-        # Release the live device state before uploading the restored one:
-        # holding both would need twice the state in device memory, which a
-        # chip filled by its optimizer state does not have.
+    def _release_state(self) -> None:
+        # A restore calls this before its first upload: holding the live
+        # device state and the restored one would need twice the state in
+        # device memory, which a chip filled by its optimizer state does
+        # not have.
         self.state = None
-        self.state = jax.tree.map(jnp.asarray, np_state)
+
+    def _set_state(self, state: dict[str, Any]) -> None:
+        # The restore released the live state (``_release_state``) before
+        # uploading, and hands over the restored tree already on the device.
+        self.state = state
 
     def _build_train_step(self):
         model, tcfg = self.model, self.tcfg

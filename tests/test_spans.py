@@ -21,7 +21,7 @@ from repro.runtime.state import ShardedStateEntity, ShardPlan
 from repro.runtime.trainer import Trainer, TrainerConfig
 
 CAPTURE_CHILDREN = ("capture_d2h", "capture_pack", "capture_checksum")
-RESTORE_CHILDREN = ("restore_rebuild", "restore_merge", "restore_upload")
+RESTORE_CHILDREN = ("restore_rebuild", "restore_upload", "restore_merge")
 TICK_CHILDREN = ("tick_control", "decode_step", "tick_update")
 
 
@@ -110,9 +110,9 @@ def test_restore_children_in_order_inside_recover(killed_run):
     (rec,) = _named(evs, "recover")
     (res,) = _named(evs, "restore")
     assert _inside(res, rec)
-    rebuild, merge, upload = (_named(evs, n)[0] for n in RESTORE_CHILDREN)
-    assert rebuild["t0"] + rebuild["dur"] <= merge["t0"]
-    assert merge["t0"] + merge["dur"] <= upload["t0"]
+    rebuild, upload, merge = (_named(evs, n)[0] for n in RESTORE_CHILDREN)
+    assert rebuild["t0"] + rebuild["dur"] <= upload["t0"]
+    assert upload["t0"] + upload["dur"] <= merge["t0"]
 
 
 @pytest.mark.parametrize("n_ranks", [2, 4, 3])
