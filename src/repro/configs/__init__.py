@@ -10,6 +10,7 @@ from repro.configs.llama3_2_1b import CONFIG as _llama32_1b
 from repro.configs.gemma2_2b import CONFIG as _gemma2_2b
 from repro.configs.gemma_7b import CONFIG as _gemma_7b
 from repro.configs.granite_3_8b import CONFIG as _granite_3_8b
+from repro.configs.granite_4_0_h_small import CONFIG as _granite_4_0_h_small
 from repro.configs.mixtral_8x7b import CONFIG as _mixtral_8x7b
 from repro.configs.grok_1_314b import CONFIG as _grok_1_314b
 from repro.configs.mamba2_780m import CONFIG as _mamba2_780m
@@ -24,6 +25,7 @@ CONFIGS: dict[str, ModelConfig] = {
         _gemma2_2b,
         _gemma_7b,
         _granite_3_8b,
+        _granite_4_0_h_small,
         _mixtral_8x7b,
         _grok_1_314b,
         _mamba2_780m,

@@ -38,12 +38,29 @@ class ModelConfig:
     final_softcap: float | None = None   # gemma2: 30.0, grok: 30.0
     tie_embeddings: bool = False
     scale_embed: bool = False            # gemma: h *= sqrt(d_model)
+    use_rope: bool = True                # False: no positional encoding (NoPE)
+
+    # Granite's scalars; the defaults are the plain decoder's.
+    embedding_multiplier: float = 1.0    # h = embed(tokens) * m
+    attention_multiplier: float | None = None  # score scale; None -> 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0     # h += m * branch, on both residual branches
+    logits_scaling: float = 1.0          # logits = head(h) / s
 
     # MoE
     num_experts: int = 0
     experts_per_tok: int = 0
     moe_every: int = 1  # MoE replaces the dense MLP in every k-th layer
     moe_capacity_factor: float = 1.25  # >= num_experts/experts_per_tok -> dropless
+    # Dropless routing (models/moe.py apply_dropless): the tokens routed to
+    # the held experts are sorted by expert and run as grouped products; no
+    # capacity, so no token is dropped. The capacity path above otherwise.
+    moe_dropless: bool = False
+    shared_d_ff: int = 0               # >0: a shared SwiGLU expert beside the routed ones
+    # The contiguous block of experts this chip holds (expert parallelism):
+    # the router scores all num_experts, the layer computes only the held
+    # experts' part of the result. 0 held -> all of them.
+    expert_start: int = 0
+    experts_held: int = 0
 
     # SSM (Mamba2 SSD)
     ssm_state: int = 0
@@ -78,6 +95,12 @@ class ModelConfig:
         )
         for k in self.layer_pattern:
             assert k in LAYER_KINDS, k
+        if self.experts_held or self.shared_d_ff:
+            assert self.moe_dropless, f"{self.name}: a held block or shared expert needs moe_dropless"
+        assert 0 <= self.expert_start and self.expert_start + self.n_experts_held <= self.num_experts, (
+            f"{self.name}: held experts [{self.expert_start}, +{self.n_experts_held}) "
+            f"outside {self.num_experts}"
+        )
 
     # ------------------------------------------------------------------ #
     @property
@@ -118,6 +141,11 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def n_experts_held(self) -> int:
+        """Experts whose weights this chip holds (all of them unless a block is set)."""
+        return self.experts_held or self.num_experts
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -132,6 +160,10 @@ class ModelConfig:
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests (per the assignment)."""
         period = len(self.layer_pattern)
+        n_exp = min(self.num_experts, 4)
+        # The held block keeps its place and share among the fewer experts.
+        held = max(1, self.experts_held * n_exp // self.num_experts) if self.experts_held else 0
+        start = min(self.expert_start * n_exp // self.num_experts, n_exp - held) if held else 0
         return replace(
             self,
             name=f"{self.name}-reduced",
@@ -142,7 +174,11 @@ class ModelConfig:
             head_dim=16,
             d_ff=128,
             vocab_size=256,
-            num_experts=min(self.num_experts, 4),
+            num_experts=n_exp,
+            experts_per_tok=min(self.experts_per_tok, n_exp),
+            shared_d_ff=128 if self.shared_d_ff else 0,
+            expert_start=start,
+            experts_held=held,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_headdim=16 if self.ssm_state else self.ssm_headdim,
             vision_tokens=16 if self.vision_tokens else 0,

@@ -85,8 +85,10 @@ def blockwise_attention(
     q_offset: int = 0,
     q_chunk: int = 1024,
     k_chunk: int = 1024,
+    scale: float | None = None,
 ) -> jax.Array:
-    """Online-softmax blockwise attention. Returns (B, Sq, H, D).
+    """Online-softmax blockwise attention. Returns (B, Sq, H, D). Scores are
+    q.k times ``scale`` (default 1/sqrt(D)).
 
     k/v arrive with KV heads and are expanded to H (repeat_kv) so the head
     axis shards uniformly over "model"."""
@@ -94,7 +96,8 @@ def blockwise_attention(
     Sk, KV = k.shape[1], k.shape[2]
     k = repeat_kv(k, H // KV)
     v = repeat_kv(v, H // KV)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
 
     q_chunk = min(q_chunk, Sq)
     k_chunk = min(k_chunk, Sk)
@@ -191,7 +194,7 @@ def apply(
     v = constrain(v, ctx, ("batch", "seq", "kv_heads", None))
 
     causal = kind != "cross" and not cfg.is_encoder
-    if kind != "cross":
+    if kind != "cross" and cfg.use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     window = cfg.sliding_window if kind == "local" else None
@@ -204,6 +207,7 @@ def apply(
         q_offset=q_offset,
         q_chunk=cfg.attn_chunk,
         k_chunk=cfg.attn_chunk,
+        scale=cfg.attention_multiplier,
     )
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"]).astype(x.dtype)
     return constrain(out, ctx, ("batch", "seq", "act_embed")), {"k": k, "v": v}
@@ -241,10 +245,12 @@ def decode(
         new_cache = cache
         valid = jnp.ones((k.shape[1],), bool)
     else:
-        q = apply_rope(q, pos[None, None] if pos.ndim == 0 else pos, cfg.rope_theta)
         knew = jnp.einsum("bsd,dhk->bshk", x, params["wk"]).astype(cfg.compute_dtype)
         vnew = jnp.einsum("bsd,dhk->bshk", x, params["wv"]).astype(cfg.compute_dtype)
-        knew = apply_rope(knew, pos[None, None] if pos.ndim == 0 else pos, cfg.rope_theta)
+        if cfg.use_rope:
+            positions = pos[None, None] if pos.ndim == 0 else pos
+            q = apply_rope(q, positions, cfg.rope_theta)
+            knew = apply_rope(knew, positions, cfg.rope_theta)
         k = jax.lax.dynamic_update_slice_in_dim(cache["k"], knew, pos, axis=1)
         v = jax.lax.dynamic_update_slice_in_dim(cache["v"], vnew, pos, axis=1)
         new_cache = {"k": k, "v": v}
@@ -258,7 +264,8 @@ def decode(
     G = H // KV
     qg = q.reshape(B, 1, KV, G, D)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k, preferred_element_type=jnp.float32)
-    s = softcap(s / math.sqrt(D), cfg.attn_softcap)
+    s = s * cfg.attention_multiplier if cfg.attention_multiplier is not None else s / math.sqrt(D)
+    s = softcap(s, cfg.attn_softcap)
     s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)

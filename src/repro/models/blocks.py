@@ -19,6 +19,12 @@ def slot_is_moe(cfg: ModelConfig, slot: int) -> bool:
     return cfg.has_moe and cfg.d_ff > 0 and (slot % cfg.moe_every == cfg.moe_every - 1)
 
 
+def _residual(h: jax.Array, branch: jax.Array, cfg: ModelConfig) -> jax.Array:
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return h + branch
+
+
 def block_specs(cfg: ModelConfig, slot: int) -> dict[str, Any]:
     kind = cfg.layer_pattern[slot]
     specs: dict[str, Any] = {"ln1": rmsnorm_spec(cfg.d_model)}
@@ -48,13 +54,15 @@ def apply_block(
 
     hin = rmsnorm(h, params["ln1"], cfg.norm_eps)
     if kind == "mamba":
-        mixed, cache = ssm.apply(params["mixer"], hin, cfg, ctx=ctx)
+        with jax.named_scope("ssm"):
+            mixed, cache = ssm.apply(params["mixer"], hin, cfg, ctx=ctx)
     else:
-        mixed, cache = attention.apply(
-            params["mixer"], hin, cfg, kind=kind, ctx=ctx,
-            kv_src=vision_kv if kind == "cross" else None, q_offset=q_offset,
-        )
-    h = h + mixed
+        with jax.named_scope("attention"):
+            mixed, cache = attention.apply(
+                params["mixer"], hin, cfg, kind=kind, ctx=ctx,
+                kv_src=vision_kv if kind == "cross" else None, q_offset=q_offset,
+            )
+    h = _residual(h, mixed, cfg)
 
     if cfg.d_ff > 0:
         hin = rmsnorm(h, params["ln2"], cfg.norm_eps)
@@ -62,7 +70,7 @@ def apply_block(
             out, aux = moe.apply(params["ffn"], hin, cfg, ctx=ctx)
         else:
             out = mlp.apply(params["ffn"], hin, cfg, ctx=ctx)
-        h = h + out
+        h = _residual(h, out, cfg)
     return h, cache, aux
 
 
@@ -79,10 +87,12 @@ def decode_block(
     kind = cfg.layer_pattern[slot]
     hin = rmsnorm(h, params["ln1"], cfg.norm_eps)
     if kind == "mamba":
-        mixed, new_cache = ssm.decode(params["mixer"], hin, cache, cfg, ctx=ctx)
+        with jax.named_scope("ssm"):
+            mixed, new_cache = ssm.decode(params["mixer"], hin, cache, cfg, ctx=ctx)
     else:
-        mixed, new_cache = attention.decode(params["mixer"], hin, cache, pos, cfg, kind=kind, ctx=ctx)
-    h = h + mixed
+        with jax.named_scope("attention"):
+            mixed, new_cache = attention.decode(params["mixer"], hin, cache, pos, cfg, kind=kind, ctx=ctx)
+    h = _residual(h, mixed, cfg)
 
     if cfg.d_ff > 0:
         hin = rmsnorm(h, params["ln2"], cfg.norm_eps)
@@ -90,7 +100,7 @@ def decode_block(
             out, _ = moe.apply(params["ffn"], hin, cfg, ctx=ctx, num_groups=1)
         else:
             out = mlp.apply(params["ffn"], hin, cfg, ctx=ctx)
-        h = h + out
+        h = _residual(h, out, cfg)
     return h, new_cache
 
 

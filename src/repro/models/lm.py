@@ -61,9 +61,8 @@ def active_param_count(cfg: ModelConfig) -> int:
     from repro.models import moe as moe_mod
 
     moe_slots = [j for j in range(cfg.period) if blocks.slot_is_moe(cfg, j)]
-    per_slot = tree_count(moe_mod.abstract_params(cfg)) - tree_count(
-        {"router": moe_mod.abstract_params(cfg)["router"]}
-    )
+    spec = moe_mod.abstract_params(cfg)
+    per_slot = tree_count({k: v for k, v in spec.items() if k not in ("router", "shared")})
     inactive_frac = 1.0 - cfg.experts_per_tok / cfg.num_experts
     inactive = int(len(moe_slots) * cfg.num_periods * per_slot * inactive_frac)
     return total - inactive
@@ -77,6 +76,8 @@ def _embed_tokens(params: dict[str, Any], tokens: jax.Array, cfg: ModelConfig, c
     h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
     if cfg.scale_embed:
         h = h * math.sqrt(cfg.d_model)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
     return constrain(h, ctx, ("batch", "seq", "act_embed"))
 
 
@@ -185,6 +186,8 @@ def _head_weight(params: dict[str, Any], cfg: ModelConfig) -> jax.Array:
 def logits_fn(params: dict[str, Any], h: jax.Array, cfg: ModelConfig, ctx: ShardCtx | None) -> jax.Array:
     w = _head_weight(params, cfg)
     logits = jnp.einsum("...d,dv->...v", h, w).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     logits = softcap(logits, cfg.final_softcap)
     if cfg.padded_vocab != cfg.vocab_size:
         pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size

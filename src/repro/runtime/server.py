@@ -104,6 +104,36 @@ def start_metrics_server(registry_fn: Callable[[], Any], port: int = 0) -> Metri
     return MetricsServer(registry_fn, port)
 
 
+def session_byte_labels(cfg: Any, sessions: dict[str, Any], since: int | None) -> dict[str, int]:
+    """Which bytes of a session tree a save captures, for the ``capture_d2h``
+    span: ``kv_bytes``, the append-only leaves (attention K/V and the token
+    array); ``recurrent_bytes``, the leaves every token rewrites (SSM state and
+    conv window, the position counter); ``clean_bytes``, the bytes unchanged
+    since the save at position ``since`` (None: no such save, all dirty).
+
+    At position ``pos`` the K/V rows ``[since, pos)`` and the tokens
+    ``(since, pos]`` were written since that save; every other row of an
+    append-only leaf, written earlier or not yet, is unchanged."""
+    pos = int(sessions["pos"])
+    kv = rec = clean = 0
+
+    def append_only(leaf, axis: int, written: int) -> None:
+        nonlocal kv, clean
+        kv += leaf.nbytes
+        if since is not None:
+            clean += leaf.nbytes - leaf.nbytes // leaf.shape[axis] * written
+
+    for j, kind in enumerate(cfg.layer_pattern):
+        for leaf in sessions["cache"][f"slot{j}"].values():
+            if kind == "mamba":
+                rec += leaf.nbytes
+            else:  # (periods, B, S, KV, hd); cross-attention K/V never change
+                append_only(leaf, -3, 0 if kind == "cross" else pos - (since or 0))
+    append_only(sessions["tokens"], 1, pos - (since or 0))
+    rec += sessions["pos"].nbytes
+    return {"kv_bytes": kv, "recurrent_bytes": rec, "clean_bytes": clean}
+
+
 @dataclass
 class ServerConfig:
     batch: int = 4
@@ -142,6 +172,7 @@ class Server:
         self.params = params if params is not None else model.init(jax.random.PRNGKey(0))
 
         self.sessions: dict[str, Any] = {}  # cache/tokens/pos once prefilled
+        self._saved_pos: int | None = None  # position of the last session capture
         self._prefill = jax.jit(
             lambda p, toks, **kw: model.prefill(p, tokens=toks, **kw)
         )
@@ -203,7 +234,10 @@ class Server:
         eng = CheckpointEngine(n_ranks, self.scfg.engine)
         eng.register(
             "sessions",
-            ShardedStateEntity(lambda: self.sessions, self._set_sessions, self.plan),
+            ShardedStateEntity(
+                lambda: self.sessions, self._set_sessions, self.plan,
+                capture_labels=lambda s: session_byte_labels(self.model.cfg, s, self._saved_pos),
+            ),
         )
         return eng
 
@@ -243,6 +277,7 @@ class Server:
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         tokens = tokens.at[:, P].set(nxt)
         self.sessions = {"cache": cache, "tokens": tokens, "pos": jnp.asarray(P, jnp.int32)}
+        self._saved_pos = None
 
     def decode(self, n_tokens: int) -> np.ndarray:
         """Greedy-decode n_tokens for every session, fault-tolerantly."""
@@ -306,6 +341,7 @@ class Server:
                             ok = self.engine.checkpoint({"pos": pos + 1})
                             if ok and self.replica is not None:
                                 self._replica_tick()
+                        self._saved_pos = pos + 1 if ok else None
                         if not ok:
                             raise ProcessFaultException(
                                 sorted(self.cluster.failed), "checkpoint"
@@ -333,7 +369,9 @@ class Server:
         self._prompt_len = prompts.shape[1]
         self.prefill(prompts, **extra)
         # First checkpoint right after prefill (the serving baseline state).
-        if self.engine.checkpoint({"pos": int(self.sessions["pos"])}):
+        pos = int(self.sessions["pos"])
+        if self.engine.checkpoint({"pos": pos}):
+            self._saved_pos = pos
             if self.replica is not None:
                 self._replica_tick()
         return self.decode(n_tokens)
@@ -350,6 +388,7 @@ class Server:
         """Recovery entry: the replication rung sits ABOVE the codec ladder —
         a synced shadow team is promoted (no blocking rebuild) and only teams
         without a promotable shadow fall into the restore machinery."""
+        self._saved_pos = None  # the next save counts every byte as changed
         with _TR.span("recover"):
             if self.replica is not None and self.replica.can_promote:
                 self._promote_replica()

@@ -99,6 +99,10 @@ class ShardedStateEntity:
     before its first host-to-device transfer, so the device never holds the
     old state and the restored one at once. ``set_state`` receives a tree of
     device arrays.
+
+    ``capture_labels``, when given, maps the live state to further labels of
+    the ``capture_d2h`` span (which of its bytes are which); it is called
+    only while tracing is on.
     """
 
     def __init__(
@@ -107,10 +111,12 @@ class ShardedStateEntity:
         set_state: Callable[[Any], None],
         plan: ShardPlan,
         release: Callable[[], None] | None = None,
+        capture_labels: Callable[[Any], dict[str, int]] | None = None,
     ) -> None:
         self._get = get_state
         self._set = set_state
         self._release = release
+        self._labels = capture_labels
         self.plan = plan
 
     def shard_coords(self, n_ranks: int) -> list[list[LeafSlice]]:
@@ -119,7 +125,8 @@ class ShardedStateEntity:
     # -- snapshot ------------------------------------------------------------
     def snapshot_shards(self, n_ranks: int) -> list[Any]:
         live = self._get()
-        with _TR.child("capture_d2h", bytes=sum(x.nbytes for x in jax.tree.leaves(live))):
+        labels = self._labels(live) if self._labels is not None and _TR.enabled else {}
+        with _TR.child("capture_d2h", bytes=sum(x.nbytes for x in jax.tree.leaves(live)), **labels):
             state = jax.device_get(live)
         leaves = self.plan.treedef.flatten_up_to(state)
         shard_leaves: list[list[np.ndarray]] = [[] for _ in range(n_ranks)]
