@@ -75,7 +75,7 @@ from repro.core import gf256
 from repro.core import parity as parity_mod
 from repro.core import storage as storage_mod
 from repro.core.hoststore import HostStore, StorePayload
-from repro.core.integrity import IntegrityError, np_checksum
+from repro.core.integrity import IntegrityError, np_checksum, payload_checksum
 from repro.core.serialization import Manifest, dtype_from_name, pack_bytes, unpack_bytes
 from repro.core.snapshot import SnapshotRegistry, Snapshottable
 from repro.obs.journal import EventJournal
@@ -777,11 +777,15 @@ class CheckpointEngine:
             return lambda nbytes: store.lease(key, nbytes)
 
         # Every entity's shards first (a sharded state's D2H fetch is timed
-        # inside its snapshot_shards), then all packing under one span.
-        snaps = {
-            name: ent.snapshot_shards(self.n_ranks)
-            for name, ent in self._entities.items()
-        }
+        # inside it), then all packing under one span. An entity on the
+        # device also hands over its pieces' checksums, summed there before
+        # the D2H (``capture_shards``).
+        snaps, piece_sums = {}, {}
+        for name, ent in self._entities.items():
+            if self.cfg.validate and hasattr(ent, "capture_shards"):
+                snaps[name], piece_sums[name] = ent.capture_shards(self.n_ranks)
+            else:
+                snaps[name] = ent.snapshot_shards(self.n_ranks)
         with _TR.span("capture_pack", eng=eng, gen=gen) as sp:
             for name, ent in self._entities.items():
                 shards = snaps.pop(name)
@@ -850,7 +854,15 @@ class CheckpointEngine:
         codec_specs = {
             name: self._codec_spec(self._codec_for(name)) for name in packed
         }
-        with _TR.span("capture_checksum", eng=eng, gen=gen):
+        with _TR.span("capture_checksum", eng=eng, gen=gen) as sp:
+            # The reference sums of each own payload: the device's piece sums
+            # (one fetch per entity) placed at their offsets, the rest summed
+            # over the packed bytes here.
+            pieces = {
+                name: ps.per_rank(self.n_ranks)
+                for name, ps in piece_sums.items() if ps is not None
+            }
+            device_bytes = host_bytes = 0
             for r in alive0:
                 payload = StorePayload(meta=dict(meta or {}))
                 if coords_tables:
@@ -866,12 +878,18 @@ class CheckpointEngine:
                     ):
                         payload.own_exch[name] = packed_partner[name][r]
                     if self.cfg.validate:
-                        payload.meta.setdefault("checksums", {})[name] = np_checksum(flat)
+                        sums, known = payload_checksum(
+                            flat, man.offsets, pieces[name][r] if name in pieces else None
+                        )
+                        payload.meta.setdefault("checksums", {})[name] = sums
+                        device_bytes += known
+                        host_bytes += flat.nbytes - known
                 if self.cfg.validate:
                     payload.meta["exch_checksums"] = exch_sums
                 if self.cfg.delta:
                     payload.meta["exch_chunk_sums"] = chunk_sums
                 self.stores[r].buffer.write(payload)
+            sp.label(device_bytes=device_bytes, host_bytes=host_bytes)
         self.stats.last_bytes_staged = bytes_staged
         return packed_partner, manifests, exch_sums, chunk_sums
 

@@ -43,6 +43,46 @@ def np_checksum(buf: np.ndarray) -> tuple[int, int]:
     return s1, s2
 
 
+def payload_checksum(
+    flat: np.ndarray, offsets: list[int], pieces: list[tuple[int, int] | None] | None
+) -> tuple[tuple[int, int], int]:
+    """``np_checksum(flat)`` of a packed payload, assembled from its leaves'
+    sums where they are known.
+
+    ``pieces[i]`` is leaf i's (s1, s2) as if its bytes began at word 0, or
+    None. A leaf that starts on a word boundary contributes ``s1`` and
+    ``s2 + (offset / 4)·s1`` (the sums are linear in the words); every other
+    byte is summed here, in runs between such leaves, each run zero-filled to
+    its word boundary so that no byte is counted twice. Exact for any offsets
+    and lengths. Returns the sums and the bytes that came from ``pieces``."""
+    s1 = s2 = 0
+
+    def add(c1: int, c2: int, word: int) -> None:
+        nonlocal s1, s2
+        s1 = (s1 + c1) & 0xFFFFFFFF
+        s2 = (s2 + c2 + word * c1) & 0xFFFFFFFF
+
+    def host(lo: int, hi: int) -> None:
+        if hi > lo:
+            head = lo % 4
+            run = flat[lo:hi]
+            if head:
+                run = np.concatenate([np.zeros(head, np.uint8), run])
+            add(*np_checksum(run), lo // 4)
+
+    known = lo = 0
+    ends = list(offsets[1:]) + [flat.nbytes]
+    for off, end, p in zip(offsets, ends, pieces or [None] * len(offsets)):
+        if p is None or off % 4:
+            continue
+        host(lo, off)
+        add(p[0], p[1], off // 4)
+        known += end - off
+        lo = end
+    host(lo, flat.nbytes)
+    return (s1, s2), known
+
+
 def np_tree_checksum(leaves: list[np.ndarray]) -> tuple[int, int]:
     acc1, acc2 = 0, 0
     for i, leaf in enumerate(leaves):

@@ -15,6 +15,7 @@ semantics of the 512-chip job.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -88,6 +89,94 @@ class ShardPlan:
         return out
 
 
+def _word_lanes(dtype: Any) -> int | None:
+    """Elements of ``dtype`` in a 32-bit word (4, 2 or 1), or None for a
+    dtype the device sum does not read: wider than a word, or packed below a
+    byte on the device."""
+    bits = jax.dtypes.itemsize_bits(dtype)
+    return 32 // bits if bits in (8, 16, 32) else None
+
+
+def _device_summable(x: Any, dim: int | None, n: int) -> bool:
+    """Whether leaf ``x`` is summed on the device: a device array of a
+    dtype :func:`_word_lanes` reads, whose piece boundaries fall on whole
+    words of the piece (``_piece_sums`` shifts each piece by whole words)."""
+    if not isinstance(x, jax.Array):
+        return False
+    m = _word_lanes(x.dtype)
+    if m is None or x.size >= 1 << 32:
+        return False
+    if dim is None:
+        return True
+    block = int(np.prod(x.shape[dim:], dtype=np.int64)) // n
+    return block % m == 0
+
+
+def _piece_sums(x: jax.Array, dim: int | None, n: int) -> jax.Array:
+    """(2, pieces) uint32: ``np_checksum``'s (s1, s2) of each piece the pack
+    writes of leaf ``x``, as if the piece began at word 0. The pieces are
+    the ``n`` blocks on ``dim`` in C order, or the whole leaf (``dim`` None).
+
+    An element of e bytes at index i of its piece fills lane ``i mod (4/e)``
+    of word ``i // (4/e)`` (little-endian, as the host reads the bytes), so
+    the sums are one fused reduction over the leaf as it lies: no copy, no
+    padding, no relayout."""
+    shape = x.shape
+    e = x.dtype.itemsize
+    m = 4 // e
+    if x.dtype == jnp.bool_:
+        u = x.astype(jnp.uint32)
+    else:
+        u = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * e}")).astype(jnp.uint32)
+    # Element index in the piece's C order, with the split dim counted from
+    # the leaf's start: piece r reads ``r * block`` higher, corrected below.
+    idx = jnp.zeros(shape, jnp.uint32)
+    stride, block = 1, 0
+    for k in reversed(range(len(shape))):
+        idx = idx + jax.lax.broadcasted_iota(jnp.uint32, shape, k) * jnp.uint32(stride)
+        stride *= shape[k] // n if k == dim else shape[k]
+        if k == dim:
+            block = stride
+    if m > 1:
+        u = u << ((idx & (m - 1)) * (8 * e)).astype(jnp.uint32)
+    wu = ((idx >> (m.bit_length() - 1)) + 1) * u
+    if dim is None:
+        return jnp.stack([jnp.sum(u, dtype=jnp.uint32), jnp.sum(wu, dtype=jnp.uint32)])[:, None]
+    axes = tuple(k for k in range(len(shape)) if k != dim)
+    s1 = jnp.sum(u, axis=axes, dtype=jnp.uint32).reshape(n, -1).sum(1, dtype=jnp.uint32)
+    s2 = jnp.sum(wu, axis=axes, dtype=jnp.uint32).reshape(n, -1).sum(1, dtype=jnp.uint32)
+    s2 = s2 - jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(block // m) * s1
+    return jnp.stack([s1, s2])
+
+
+@partial(jax.jit, static_argnames=("dims", "n"))
+def _tree_piece_sums(leaves: list[jax.Array], dims: tuple, n: int) -> jax.Array:
+    return jnp.concatenate([_piece_sums(x, d, n) for x, d in zip(leaves, dims)], axis=1)
+
+
+@dataclass
+class PieceSums:
+    """The capture's device-computed sums of the pieces a pack writes: one
+    (2, columns) uint32 array, still on the device, fetched in one transfer
+    by :meth:`per_rank`. ``cols[i]`` is leaf i's first column (None: the
+    leaf is summed on the host), ``split[i]`` whether it has a column per
+    rank or one for every rank."""
+
+    pairs: jax.Array
+    cols: list[int | None]
+    split: list[bool]
+
+    def per_rank(self, n_ranks: int) -> list[list[tuple[int, int] | None]]:
+        """Per rank, each leaf's (s1, s2) as ``integrity.payload_checksum``
+        takes them."""
+        s1, s2 = np.asarray(jax.device_get(self.pairs)).tolist()
+        return [
+            [None if c is None else (s1[c + r * sp], s2[c + r * sp])
+             for c, sp in zip(self.cols, self.split)]
+            for r in range(n_ranks)
+        ]
+
+
 class ShardedStateEntity:
     """DistributedEntity over a live state accessed via get/set callbacks.
 
@@ -103,6 +192,10 @@ class ShardedStateEntity:
     ``capture_labels``, when given, maps the live state to further labels of
     the ``capture_d2h`` span (which of its bytes are which); it is called
     only while tracing is on.
+
+    :meth:`capture_shards` also returns the device's checksums of every
+    piece the pack will write (:class:`PieceSums`), so the engine's
+    capture-time reference is taken over the bytes the device holds.
     """
 
     def __init__(
@@ -124,9 +217,18 @@ class ShardedStateEntity:
 
     # -- snapshot ------------------------------------------------------------
     def snapshot_shards(self, n_ranks: int) -> list[Any]:
+        return self.capture_shards(n_ranks, sums=False)[0]
+
+    def capture_shards(
+        self, n_ranks: int, sums: bool = True
+    ) -> tuple[list[Any], PieceSums | None]:
+        """Per-rank shards, and (``sums``) the device's sums of their pieces:
+        one program dispatched over the live device leaves before the D2H,
+        its result left on the device. None when no leaf lives there."""
         live = self._get()
         labels = self._labels(live) if self._labels is not None and _TR.enabled else {}
         with _TR.child("capture_d2h", bytes=sum(x.nbytes for x in jax.tree.leaves(live)), **labels):
+            piece_sums = self._dispatch_sums(live, n_ranks) if sums else None
             state = jax.device_get(live)
         leaves = self.plan.treedef.flatten_up_to(state)
         shard_leaves: list[list[np.ndarray]] = [[] for _ in range(n_ranks)]
@@ -140,7 +242,22 @@ class ShardedStateEntity:
                 pieces = np.split(a, n_ranks, axis=dim)
                 for r in range(n_ranks):
                     shard_leaves[r].append(pieces[r])
-        return [self.plan.treedef.unflatten(ls) for ls in shard_leaves]
+        return [self.plan.treedef.unflatten(ls) for ls in shard_leaves], piece_sums
+
+    def _dispatch_sums(self, live: Any, n_ranks: int) -> PieceSums | None:
+        leaves = self.plan.treedef.flatten_up_to(live)
+        dims = [self.plan.split_dim(i, n_ranks) for i in range(len(leaves))]
+        take = [i for i, x in enumerate(leaves) if _device_summable(x, dims[i], n_ranks)]
+        if not take:
+            return None
+        cols: list[int | None] = [None] * len(leaves)
+        split = [d is not None for d in dims]
+        c = 0
+        for i in take:
+            cols[i] = c
+            c += n_ranks if split[i] else 1
+        pairs = _tree_piece_sums([leaves[i] for i in take], tuple(dims[i] for i in take), n_ranks)
+        return PieceSums(pairs, cols, split)
 
     # -- partner exchange subset (paper §5.2.1: replicated data needs no
     #    exchange — only uniquely-owned leaves travel to the partner) --------

@@ -93,6 +93,13 @@ def test_capture_children_in_order_and_labeled(killed_run):
         state_bytes = sum(x.nbytes for x in jax.tree.leaves(trainer.state))
         assert d2h["args"]["bytes"] == state_bytes
         assert 0 < pack["args"]["replicated_bytes"] < pack["args"]["bytes"]
+        # The train state's own payloads are summed on the device; the data
+        # pipeline and the timers, host entities, on the host.
+        n = trainer.engine.n_ranks
+        own = sum(x.nbytes * (1 if trainer.plan.split_dim(i, n) is not None else n)
+                  for i, x in enumerate(trainer.plan.treedef.flatten_up_to(trainer.state)))
+        assert check["args"]["device_bytes"] == own
+        assert check["args"]["host_bytes"] > 0
 
 
 @pytest.mark.parametrize("child", RESTORE_CHILDREN)
@@ -140,6 +147,29 @@ def test_replicated_bytes_count_each_rank_copy(tr, n_ranks):
     assert pack["args"]["replicated_bytes"] == want
     assert pack["args"]["bytes"] == eng.stats.last_bytes_staged
     assert want == n_ranks * (live["b"].nbytes + (live["c"].nbytes if n_ranks == 3 else 0))
+    eng.close()
+
+
+@pytest.mark.parametrize("on_device", [True, False])
+def test_checksum_labels_sum_to_the_own_payloads(tr, on_device):
+    """``capture_checksum``'s ``device_bytes`` and ``host_bytes`` split the
+    bytes of every own payload by where their reference sums came from."""
+    sds = {"a": jax.ShapeDtypeStruct((12, 6), jnp.float32),
+           "b": jax.ShapeDtypeStruct((2, 8), jnp.bfloat16)}
+    plan = ShardPlan.from_pspecs(sds, {"a": P("data"), "b": P(None, "data")})
+    rng = np.random.default_rng(1)
+    live = {k: rng.standard_normal(s.shape).astype(s.dtype) for k, s in sds.items()}
+    if on_device:
+        live = jax.device_put(live)
+    eng = CheckpointEngine(4, EngineConfig())
+    eng.register("state", ShardedStateEntity(lambda: live, lambda s: None, plan))
+    assert eng.checkpoint({"step": 1})
+    (check,) = _named(tr.events(), "capture_checksum")
+    own = sum(st.buffer.read_only.own["state"][0].nbytes for st in eng.stores.values())
+    assert own == 12 * 6 * 4 + 2 * 8 * 2
+    want = {"device_bytes": own, "host_bytes": 0} if on_device else {
+        "device_bytes": 0, "host_bytes": own}
+    assert {k: check["args"][k] for k in want} == want
     eng.close()
 
 
